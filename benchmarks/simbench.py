@@ -63,7 +63,9 @@ so the perf trajectory is tracked across PRs (uploaded as a CI artifact by
                  simulated time, so it is deterministic per profile
   device_collective  the sim-to-silicon loop (``repro.device``) on an
                  emulated 8-device host mesh (subprocess with
-                 ``XLA_FLAGS=--xla_force_host_platform_device_count=8``):
+                 ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
+                 and ``JAX_PLATFORMS=cpu``, so it never reaches for a
+                 chip the parent holds):
                  executes the compiled BBS plan end to end, gates the
                  measured cycle throughput (floor) and the Hockney-
                  calibration prediction error (ceiling, the paper-facing
@@ -854,6 +856,9 @@ def bench_device(smoke: bool) -> None:
     """)
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # this parent has already run jax cells; on a TPU host it holds the
+    # chips, so the emulated mesh stays on the host CPU
+    env["JAX_PLATFORMS"] = "cpu"
     proc = subprocess.run([sys.executable, "-c", code], env=env,
                           capture_output=True, text=True, timeout=600)
     if proc.returncode != 0:
@@ -886,6 +891,8 @@ def main(argv=None) -> int:
                          "gate it with benchmarks.check_regression (one "
                          "gate implementation, committed floors)")
     args = ap.parse_args(argv)
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     n = args.n or (64 if args.smoke else 256)
     bench_engines(args.topo, n, args.groups, args.message, args.repeats)

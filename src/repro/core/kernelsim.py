@@ -59,11 +59,16 @@ for the host:
     would run) uses the jitted core when the jit policy says it pays:
     always when ``REPRO_KERNEL_JIT=1``/``force`` or ``jit=True`` is
     passed, never when ``REPRO_KERNEL_JIT=0``/``off`` or jax is missing,
-    and by default only when jax sees more than one device — on a
-    single-core CPU host the XLA loop's per-step op dispatch makes it
+    and by default only when jax sees more than one host CPU device — on
+    a single-core CPU host the XLA loop's per-step op dispatch makes it
     ~0.5x the tuned numpy loop, while lane batching across devices
     amortizes it into a win; the numpy path is bit-identical either way,
     so the policy is a pure performance choice.
+
+The jitted core always runs on the host CPU (``_core_device``), whatever
+accelerator the process holds: its float64 event times are exact only
+where float64 is IEEE double, and a TPU's is not. Accelerator chips
+therefore never count towards the jit policy.
 
 ``run_lowered_batch`` vmaps the core across message-size lanes that share
 one lowered structure (same tasks, ranks, resources, dependencies — only
@@ -87,7 +92,7 @@ from repro.core.routing import CompiledTaskList
 from repro.core.simulator import SimResult
 from repro.core.topology import Topology
 
-try:                                      # CPU jit; no accelerator required
+try:                                      # CPU jit (see _core_device)
     import jax
 
     jax.config.update("jax_enable_x64", True)
@@ -115,7 +120,17 @@ def _jit_default() -> bool:
         return True
     if env in ("0", "off"):
         return False
-    return KERNEL_AVAILABLE and jax.device_count() > 1
+    return KERNEL_AVAILABLE and len(jax.devices("cpu")) > 1
+
+
+def _core_device():
+    """The device the jitted core runs on: the host CPU, always.
+
+    XLA:TPU has no IEEE float64; it emulates it with pairs of float32.
+    On a TPU v5e the core's event times came out up to 6.8e-15 relative
+    off the numpy engine (binomial on mesh2d 16x16 at 64e6 bytes), where
+    the contract is bit-identical. On the CPU they are identical."""
+    return jax.devices("cpu")[0]
 
 
 def _core(rank, res, caps, deps, durs):
@@ -222,7 +237,8 @@ class KernelSim:
     machinery, empty lists, and any environment without jax fall back to
     ``CompiledSim`` bit-identically. The ``jit`` keyword (default: the
     ``REPRO_KERNEL_JIT``/device-count policy in the module docstring)
-    picks the execution path for everything else.
+    picks the execution path for everything else; the jitted core runs
+    on the host CPU (``_core_device``).
     """
 
     def __init__(self, topo: Topology, cm: ConflictModel, root: int):
@@ -273,7 +289,7 @@ class KernelSim:
         ctl.bind(self.idx)
         stat = _static_arrays(ctl, self.idx)
         durs = np.asarray(ctl.durs, dtype=np.float64)
-        comp, seqs = _CORE(*stat, durs)
+        comp, seqs = _CORE(*jax.device_put((*stat, durs), _core_device()))
         return self._postprocess(ctl, np.asarray(comp),
                                  np.asarray(seqs, dtype=np.int64))
 
@@ -309,7 +325,8 @@ class KernelSim:
             return out
         ctl.bind(self.idx)
         stat = _static_arrays(ctl, self.idx)
-        comp, seqs = _CORE_BATCH(*stat, durs_lanes)
+        comp, seqs = _CORE_BATCH(*jax.device_put((*stat, durs_lanes),
+                                                 _core_device()))
         comp = np.asarray(comp)
         seqs = np.asarray(seqs, dtype=np.int64)
         out = []

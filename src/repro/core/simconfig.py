@@ -60,17 +60,17 @@ class DeviceConfig:
     ``dtype`` is the payload dtype the runner is compiled for; ``emulate``
     documents that the mesh is host-emulated (``XLA_FLAGS=
     --xla_force_host_platform_device_count=N`` before jax initializes) so
-    error messages and the calibration artifact can say so; ``use_pallas`` /
-    ``interpret`` gate the packed Pallas round step
-    (``repro.device.pallas_step``). Validated eagerly like every other
-    config block: a bad value raises here, not inside a jitted runner."""
+    error messages and the calibration artifact can say so; ``use_pallas``
+    selects the packed Pallas round step (``repro.device.pallas_step``),
+    which the backend compiles on a TPU and interprets on the CPU.
+    Validated eagerly like every other config block: a bad value raises
+    here, not inside a jitted runner."""
 
     mesh_shape: Optional[tuple] = None
     axis: str = "dev"
     dtype: str = "float32"
     emulate: bool = False
     use_pallas: bool = False
-    interpret: bool = False
 
     _DTYPES = ("float32", "float16", "bfloat16", "int32", "uint32", "int8",
                "uint8")

@@ -15,8 +15,7 @@ from repro.device.calibrate import (CalibratedCost, PredictionRow,
 from repro.device.executable import (DeviceDelivery, ExecutablePlan,
                                      build_executable)
 from repro.device.runner import (bbs_broadcast, binomial_broadcast,
-                                 chain_broadcast, device_mesh,
-                                 shard_map_compat)
+                                 chain_broadcast, device_mesh, node_coords)
 from repro.device.schedule import (DeviceSchedule, NotDeviceExecutable,
                                    make_device_schedule)
 
@@ -25,6 +24,6 @@ __all__ = [
     "measure_round", "predict_cycle_time", "prediction_report",
     "DeviceDelivery", "ExecutablePlan", "build_executable",
     "bbs_broadcast", "binomial_broadcast", "chain_broadcast", "device_mesh",
-    "shard_map_compat", "DeviceSchedule", "NotDeviceExecutable",
+    "node_coords", "DeviceSchedule", "NotDeviceExecutable",
     "make_device_schedule",
 ]

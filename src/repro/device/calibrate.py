@@ -104,8 +104,7 @@ def _fit_hockney(sizes: Sequence[float], times: Sequence[float],
 
 
 def measure_round(mesh, axis: str, nbytes: int, *, iters: int = 32,
-                  reps: int = 5, use_pallas: bool = False,
-                  interpret: bool = False) -> float:
+                  reps: int = 5, use_pallas: bool = False) -> float:
     """Measured seconds for one executor round at ``nbytes`` per link:
     a full ppermute ring matching (every device sends — the all-links-busy
     case the Hockney per-link charge models) followed by the packed
@@ -114,7 +113,6 @@ def measure_round(mesh, axis: str, nbytes: int, *, iters: int = 32,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
     from repro.device.pallas_step import round_step
-    from repro.device.runner import shard_map_compat
 
     n = mesh.shape[axis]
     pairs = [(i, (i + 1) % n) for i in range(n)]
@@ -126,13 +124,13 @@ def measure_round(mesh, axis: str, nbytes: int, *, iters: int = 32,
             val = buf[0]
             rec = jax.lax.ppermute(val, axis, pairs)
             buf, _val = round_step(buf, rec, 1, True, 0, True,
-                                   use_pallas=use_pallas,
-                                   interpret=interpret)
+                                   use_pallas=use_pallas)
             return buf, ()
         buf, _ = jax.lax.scan(step, buf, None, length=iters)
         return buf[None]
 
-    fn = jax.jit(shard_map_compat(body, mesh, P(), P(axis)))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P(),
+                               out_specs=P(axis), check_vma=False))
     jax.block_until_ready(fn(x))                 # compile + warm up
     best = float("inf")
     for _ in range(reps):

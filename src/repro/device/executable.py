@@ -92,8 +92,7 @@ class ExecutablePlan:
             def run(x, mesh):
                 return bbs_broadcast(
                     x, mesh, self.device.axis, self.schedule,
-                    self.num_groups, use_pallas=self.device.use_pallas,
-                    interpret=self.device.interpret)
+                    self.num_groups, use_pallas=self.device.use_pallas)
             # donate the payload buffer: the packet buffer is rewritten in
             # place across the scan, so the input allocation is reusable
             fn = self._run_fn = jax.jit(run, static_argnums=1,

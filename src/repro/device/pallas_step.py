@@ -5,14 +5,17 @@ it just received) followed by a gather (read the row it sends next). The
 XLA rendering is a ``dynamic_update_index_in_dim`` + ``dynamic_index_in_dim``
 pair — two full passes over the packet buffer's touched rows plus the copy
 XLA inserts when the buffer cannot be donated mid-loop. The packed step
-fuses both into one kernel with the buffer aliased in place
-(``input_output_aliases``), one row written and one row read per call.
+fuses both into one kernel with the buffer aliased in place in HBM
+(``input_output_aliases``); the whole buffer passes through VMEM on every
+call, which bounds the buffer size (ROADMAP S3).
 
 Same contract as the jnp reference (`round_step_ref`): indexes are
-pre-clipped masks decide whether the write/read actually happens, so the
-two paths are bit-identical (asserted in tests/test_device.py with
-``interpret=True`` — Pallas TPU kernels cannot lower to CPU; on TPU flip
-``use_pallas``)."""
+pre-clipped, masks decide whether the write/read actually happens, so the
+two paths are bit-identical. The backend decides how the kernel runs: on
+the CPU it runs in Pallas interpret mode (tests), on a TPU Mosaic compiles
+it. There is no silent switch to the jnp step: a buffer the kernel cannot
+take raises ``ValueError`` (:func:`check_kernel_limits`).
+"""
 
 from __future__ import annotations
 
@@ -20,14 +23,53 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-try:  # pallas imports fine on CPU builds; kernels lower only on TPU/interpret
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    HAVE_PALLAS = True
-except Exception:                                    # pragma: no cover
-    pl = pltpu = None
-    HAVE_PALLAS = False
+# Scoped VMEM the kernel asks Mosaic for (a TPU v5e core has 128 MiB; the
+# compiler's default scope is 16 MiB). The whole packet buffer is one VMEM
+# block, staged in and out, so the buffer may take a bit under half of it.
+VMEM_LIMIT_BYTES = 100 << 20
+# Mosaic indexes a row of a 2-D VMEM block dynamically only when one row is
+# one sublane: 16- and 8-bit dtypes pack 2 or 4 rows per sublane, and a
+# dynamic row index there is refused ("cannot statically prove that index
+# in dimension 0 is a multiple of 8").
+ROW_ITEMSIZE = 4
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def kernel_vmem_bytes(rows: int, plen: int, itemsize: int = 4) -> int:
+    """VMEM the kernel stages for a ``(rows, plen)`` buffer: the buffer in
+    and out, each tiled to (8, 128), plus the received and the sent row,
+    each a ``(1, plen)`` block tiled to 128 lanes."""
+    buf = _round_up(rows, 8) * _round_up(plen, 128) * itemsize
+    row = _round_up(plen, 128) * itemsize
+    return 2 * buf + 2 * row
+
+
+def check_kernel_limits(shape, dtype) -> None:
+    """Raise ``ValueError`` naming the limit a packet buffer breaks: rows
+    of a dtype narrower than 32 bits, or more VMEM than
+    ``VMEM_LIMIT_BYTES``. Such buffers run only on the jnp step, which
+    the caller selects (``use_pallas=False``)."""
+    dtype = jnp.dtype(dtype)
+    if dtype.itemsize != ROW_ITEMSIZE:
+        raise ValueError(
+            f"Pallas round step: {dtype} rows are packed several to a "
+            f"sublane and cannot be indexed dynamically; the kernel takes "
+            f"{ROW_ITEMSIZE}-byte dtypes only (use_pallas=False for "
+            f"{dtype})")
+    rows, plen = shape
+    need = kernel_vmem_bytes(rows, plen, dtype.itemsize)
+    if need > VMEM_LIMIT_BYTES:
+        raise ValueError(
+            f"Pallas round step: a {rows}x{plen} {dtype} packet buffer "
+            f"needs {need} bytes of VMEM, over the kernel's VMEM budget of "
+            f"VMEM_LIMIT_BYTES={VMEM_LIMIT_BYTES} (use_pallas=False for "
+            f"this size)")
 
 
 def round_step_ref(buf, rec, r_idx, r_ok, s_idx, s_ok):
@@ -44,41 +86,51 @@ def round_step_ref(buf, rec, r_idx, r_ok, s_idx, s_ok):
 
 
 def _scatter_gather_kernel(scal_ref, buf_ref, rec_ref, out_ref, val_ref):
-    # scal = [r_idx, r_ok, s_idx, s_ok]; buf aliased to out (in-place row
-    # write). The gather reads *after* the scatter so an intra-cycle forward
-    # (send a row received one sub-round earlier) sees the fresh value.
-    r_idx = scal_ref[0]
+    # scal = [r_idx, r_ok, s_idx, s_ok]; rec and val are (1, plen). Aliasing
+    # makes the HBM buffer in place, not the VMEM blocks: out is a block of
+    # its own, and inside a scan on a v5e it does not start as buf (a root
+    # that only sends got back a zeroed buffer), so copy buf first. The
+    # gather reads *after* the scatter so an intra-cycle forward (send a row
+    # received one sub-round earlier) sees the fresh value.
+    out_ref[...] = buf_ref[...]
 
     @pl.when(scal_ref[1] != 0)
     def _write():
-        out_ref[r_idx, :] = rec_ref[:]
+        out_ref[pl.ds(scal_ref[0], 1), :] = rec_ref[...]
 
-    v = out_ref[scal_ref[2], :]
-    val_ref[:] = jnp.where(scal_ref[3] != 0, v, jnp.zeros_like(v))
+    v = out_ref[pl.ds(scal_ref[2], 1), :]
+    val_ref[...] = jnp.where(scal_ref[3] != 0, v, jnp.zeros_like(v))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _round_step_pallas(buf, rec, scal, interpret=False):
-    return pl.pallas_call(
+    # the rows travel as (1, plen) blocks, laid out like a buffer row
+    out, val = pl.pallas_call(
         _scatter_gather_kernel,
         out_shape=(jax.ShapeDtypeStruct(buf.shape, buf.dtype),
-                   jax.ShapeDtypeStruct(rec.shape, rec.dtype)),
+                   jax.ShapeDtypeStruct((1,) + rec.shape, rec.dtype)),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   pl.BlockSpec(memory_space=pltpu.VMEM),
                   pl.BlockSpec(memory_space=pltpu.VMEM)],
         out_specs=(pl.BlockSpec(memory_space=pltpu.VMEM),
                    pl.BlockSpec(memory_space=pltpu.VMEM)),
         input_output_aliases={1: 0},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(scal, buf, rec)
+        name="bbs_round_step",
+    )(scal, buf, rec[None])
+    return out, val[0]
 
 
-def round_step(buf, rec, r_idx, r_ok, s_idx, s_ok, *, use_pallas=False,
-               interpret=False):
-    """The packed scatter+gather step: jnp oracle by default, the Pallas
-    kernel when ``use_pallas`` (TPU, or ``interpret=True`` for tests)."""
-    if not (use_pallas and HAVE_PALLAS):
+def round_step(buf, rec, r_idx, r_ok, s_idx, s_ok, *, use_pallas=False):
+    """The packed scatter+gather step: the jnp reference by default, the
+    Pallas kernel when ``use_pallas`` (compiled on a TPU, interpreted on
+    the CPU)."""
+    if not use_pallas:
         return round_step_ref(buf, rec, r_idx, r_ok, s_idx, s_ok)
+    check_kernel_limits(buf.shape, buf.dtype)
     scal = jnp.stack([jnp.int32(r_idx), jnp.int32(r_ok),
                       jnp.int32(s_idx), jnp.int32(s_ok)])
-    return _round_step_pallas(buf, rec, scal, interpret=interpret)
+    return _round_step_pallas(buf, rec, scal,
+                              interpret=jax.default_backend() == "cpu")

@@ -9,15 +9,17 @@ rendering of the paper's algorithm: every ICI link carries a packet every
 round — balanced saturation.
 
 ``device_mesh`` builds the execution mesh from whatever devices the process
-has; emulated runs get 8 host devices via
-``XLA_FLAGS=--xla_force_host_platform_device_count=8`` **set before jax
-initializes** (the device count cannot change afterwards — tests spawn a
-subprocess, see tests/test_device.py and docs/device.md).
+has: the chips of a TPU host, or on the CPU emulated host devices
+(``XLA_FLAGS=--xla_force_host_platform_device_count=8`` **set before jax
+initializes** — the device count cannot change afterwards, so tests spawn
+a subprocess, see tests/test_device.py and docs/device.md).
+``node_coords`` checks that every fabric edge lands on a chip-to-chip
+link.
 """
 
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -28,33 +30,40 @@ from repro.device.pallas_step import round_step
 from repro.device.schedule import _NOSEND, DeviceSchedule
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """``shard_map`` across jax versions: the experimental module spells the
-    replication-check flag ``check_rep``; newer releases promote it to
-    ``jax.shard_map`` with ``check_vma``. ppermute outputs are intentionally
-    device-varying, so the check is off either way."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        except TypeError:
-            pass
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=False)
-
-
 def device_mesh(num_devices: int, axis: str = "dev") -> Mesh:
-    """A 1-D mesh over the first ``num_devices`` process devices."""
+    """A 1-D mesh over the first ``num_devices`` process devices, one per
+    fabric node, in ``jax.devices()`` order."""
     devs = jax.devices()
     if len(devs) < num_devices:
+        platform = devs[0].platform
+        hint = (f"; emulate host devices with XLA_FLAGS="
+                f"--xla_force_host_platform_device_count={num_devices} set "
+                f"before jax initializes" if platform == "cpu" else "")
         raise RuntimeError(
-            f"need {num_devices} devices, process has {len(devs)}; for an "
-            f"emulated host mesh set XLA_FLAGS="
-            f"--xla_force_host_platform_device_count={num_devices} before "
-            f"jax initializes (e.g. in a subprocess)")
+            f"the fabric needs {num_devices} devices, one per node; the "
+            f"{platform} platform has {len(devs)}{hint}")
     return Mesh(np.array(devs[:num_devices]), (axis,))
+
+
+def node_coords(topo, devices) -> Dict[int, Tuple[int, ...]]:
+    """Physical chip coordinates of each fabric node (node ``i`` runs on
+    ``devices[i]``, the ``device_mesh`` order).
+
+    Raises ``ValueError`` when a fabric edge joins two chips that are not
+    neighbours (coordinates one step apart on one axis): that edge's
+    ppermute would cross an intermediate chip rather than one link. On a
+    v5e 2x2 host, ``jax.devices()`` enumerates (0,0), (1,0), (0,1), (1,1),
+    which puts every edge of ``torus2d(2, 2)`` on a link and two of the
+    four edges of ``ring(4)`` on diagonals."""
+    coords = {v: tuple(int(c) for c in devices[v].coords)
+              for v in range(topo.num_nodes)}
+    for u, v in topo.candidate_edges:
+        step = sum(abs(a - b) for a, b in zip(coords[u], coords[v]))
+        if step != 1:
+            raise ValueError(
+                f"fabric edge ({u}, {v}) of {topo.name} joins chips at "
+                f"{coords[u]} and {coords[v]}, which share no link")
+    return coords
 
 
 def _pad_packets(x: jax.Array, num_packets: int) -> Tuple[jax.Array, int]:
@@ -66,8 +75,7 @@ def _pad_packets(x: jax.Array, num_packets: int) -> Tuple[jax.Array, int]:
 
 
 def bbs_broadcast(x: jax.Array, mesh: Mesh, axis: str, sched: DeviceSchedule,
-                  num_groups: int, *, use_pallas: bool = False,
-                  interpret: bool = False) -> jax.Array:
+                  num_groups: int, *, use_pallas: bool = False) -> jax.Array:
     """Broadcast `x` from the schedule's root device to every device along
     `axis`. Returns the per-device copies stacked on a leading axis (callers
     that need the replicated value take [i] on their own shard).
@@ -116,7 +124,7 @@ def bbs_broadcast(x: jax.Array, mesh: Mesh, axis: str, sched: DeviceSchedule,
             s_ix, s_ok, _, _ = slot(0, c)
             zero = jnp.zeros((plen,), buf.dtype)
             buf, val = round_step(buf, zero, 0, False, s_ix, s_ok,
-                                  use_pallas=use_pallas, interpret=interpret)
+                                  use_pallas=use_pallas)
             for r in range(sched.d):
                 rec = jax.lax.ppermute(val, axis, perms[r])
                 _, _, r_ix, r_ok = slot(r, c)
@@ -125,14 +133,14 @@ def bbs_broadcast(x: jax.Array, mesh: Mesh, axis: str, sched: DeviceSchedule,
                 else:
                     ns_ix, ns_ok = 0, jnp.bool_(False)
                 buf, val = round_step(buf, rec, r_ix, r_ok, ns_ix, ns_ok,
-                                      use_pallas=use_pallas,
-                                      interpret=interpret)
+                                      use_pallas=use_pallas)
             return buf, ()
 
         buf, _ = jax.lax.scan(cycle, buf, jnp.arange(num_cycles))
         return buf[None]   # leading device axis chunk of size 1
 
-    out = shard_map_compat(body, mesh, P(), P(axis))(packets)
+    out = jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(axis),
+                        check_vma=False)(packets)
     return out[:, :total].reshape(n, total * plen)[:, :x.size] \
         .reshape((n,) + x.shape)
 
@@ -163,7 +171,8 @@ def binomial_broadcast(x: jax.Array, mesh: Mesh, axis: str,
             have = have | is_dst
         return buf[None]
 
-    return shard_map_compat(body, mesh, P(), P(axis))(x)
+    return jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(axis),
+                         check_vma=False)(x)
 
 
 def chain_broadcast(x: jax.Array, mesh: Mesh, axis: str, root: int = 0,
@@ -198,5 +207,6 @@ def chain_broadcast(x: jax.Array, mesh: Mesh, axis: str, root: int = 0,
         buf, _ = jax.lax.scan(step, buf, jnp.arange(m + n - 2))
         return buf[None]
 
-    out = shard_map_compat(body, mesh, P(), P(axis))(packets)
+    out = jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(axis),
+                        check_vma=False)(packets)
     return out.reshape(n, m * plen)[:, :x.size].reshape((n,) + x.shape)

@@ -25,6 +25,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def run_multidevice(code: str, devices: int = 8) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["JAX_PLATFORMS"] = "cpu"        # emulated devices, never a chip
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     proc = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                           capture_output=True, text=True, env=env,
@@ -125,15 +126,13 @@ def test_non_tree_baseline_rejected():
 
 
 # ---------------------------------------------------------------------------
-# Pallas round step (in-process; interpret mode runs on CPU)
+# Pallas round step (in-process; the CPU backend runs it in interpret mode)
 # ---------------------------------------------------------------------------
 
 def test_pallas_round_step_matches_oracle():
     import jax.numpy as jnp
-    from repro.device.pallas_step import HAVE_PALLAS, round_step
+    from repro.device.pallas_step import round_step
 
-    if not HAVE_PALLAS:
-        pytest.skip("pallas unavailable")
     rng = np.random.RandomState(0)
     buf = jnp.asarray(rng.rand(6, 16).astype(np.float32))
     rec = jnp.asarray(rng.rand(16).astype(np.float32))
@@ -143,9 +142,86 @@ def test_pallas_round_step_matches_oracle():
         b0, v0 = round_step(buf, rec, r_idx, r_ok, s_idx, s_ok,
                             use_pallas=False)
         b1, v1 = round_step(buf, rec, r_idx, r_ok, s_idx, s_ok,
-                            use_pallas=True, interpret=True)
+                            use_pallas=True)
         np.testing.assert_array_equal(np.asarray(b0), np.asarray(b1))
         np.testing.assert_array_equal(np.asarray(v0), np.asarray(v1))
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float16", "int8", "uint8"])
+def test_pallas_refuses_packed_row_dtypes(dtype):
+    """Rows narrower than 32 bits cannot be indexed dynamically by Mosaic:
+    the kernel says so itself, and never hands the step to jnp."""
+    import jax.numpy as jnp
+    from repro.device.pallas_step import round_step
+
+    buf = jnp.zeros((16, 256), dtype)
+    with pytest.raises(ValueError, match="packed several to a sublane"):
+        round_step(buf, buf[0], 1, True, 2, True, use_pallas=True)
+    # the jnp step takes every dtype
+    round_step(buf, buf[0], 1, True, 2, True, use_pallas=False)
+
+
+def test_pallas_refuses_buffers_past_the_vmem_budget():
+    import jax
+    import jax.numpy as jnp
+    from repro.device.pallas_step import (VMEM_LIMIT_BYTES,
+                                          check_kernel_limits,
+                                          kernel_vmem_bytes, round_step)
+
+    def step(buf):
+        return round_step(buf, buf[0], 1, True, 2, True, use_pallas=True)
+
+    rows = 8
+    plen = VMEM_LIMIT_BYTES // (2 * 4 * (rows + 1))   # just over the edge
+    plen += 1024
+    assert kernel_vmem_bytes(rows, plen) > VMEM_LIMIT_BYTES
+    with pytest.raises(ValueError, match="VMEM_LIMIT_BYTES"):
+        jax.eval_shape(step, jax.ShapeDtypeStruct((rows, plen), jnp.float32))
+    plen -= 2048
+    assert kernel_vmem_bytes(rows, plen) <= VMEM_LIMIT_BYTES
+    check_kernel_limits((rows, plen), jnp.float32)
+    jax.eval_shape(step, jax.ShapeDtypeStruct((rows, plen), jnp.float32))
+
+
+# ---------------------------------------------------------------------------
+# Device mesh and chip placement (in-process)
+# ---------------------------------------------------------------------------
+
+class _Chip:
+    def __init__(self, coords):
+        self.coords = coords
+
+
+# jax.devices() order on a TPU v5e 2x2 host
+V5E_2X2 = [_Chip(c) for c in ((0, 0, 0), (1, 0, 0), (0, 1, 0), (1, 1, 0))]
+
+
+def test_torus2d_edges_land_on_chip_links():
+    from repro.core import topology as T
+    from repro.device import node_coords
+
+    coords = node_coords(T.torus2d(2, 2), V5E_2X2)
+    assert coords == {0: (0, 0, 0), 1: (1, 0, 0), 2: (0, 1, 0), 3: (1, 1, 0)}
+
+
+def test_ring4_has_edges_between_diagonal_chips():
+    from repro.core import topology as T
+    from repro.device import node_coords
+
+    with pytest.raises(ValueError, match="share no link"):
+        node_coords(T.ring(4), V5E_2X2)
+
+
+def test_device_mesh_error_names_both_counts():
+    import jax
+    from repro.device import device_mesh
+
+    have = len(jax.devices())
+    with pytest.raises(RuntimeError) as e:
+        device_mesh(have + 3)
+    msg = str(e.value)
+    assert f"needs {have + 3} devices" in msg
+    assert f"{jax.devices()[0].platform} platform has {have}" in msg
 
 
 # ---------------------------------------------------------------------------
@@ -315,7 +391,8 @@ def test_executable_end_to_end_bit_exact():
 @pytest.mark.slow
 def test_executable_nonzero_root_and_pallas():
     """Relabeled (PlanServer) plans execute correctly from non-canonical
-    roots, and the pallas interpret round step is bit-identical."""
+    roots, and the Pallas round step (interpreted on the CPU) is
+    bit-identical."""
     run_multidevice("""
         import numpy as np, jax.numpy as jnp
         from repro import api
@@ -327,7 +404,7 @@ def test_executable_nonzero_root_and_pallas():
         for root in (0, 3, 5):
             ex = model.executable(root=root, nbytes=8192)
             assert ex.verify(x).ok, root
-        cfg = SimConfig(device=DeviceConfig(use_pallas=True, interpret=True))
+        cfg = SimConfig(device=DeviceConfig(use_pallas=True))
         ex = model.executable(root=2, nbytes=8192, config=cfg)
         assert ex.device.use_pallas
         assert ex.verify(x).ok

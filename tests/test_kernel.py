@@ -5,7 +5,8 @@ jitted core is bit-identical to the numpy engine — not approximately
 equal — on every lowered list it accepts, and every capability it lacks
 (faults, foldable lists, missing jax) delegates to the numpy engine
 rather than approximating. The jit policy (``REPRO_KERNEL_JIT`` /
-device count) is a pure performance choice, never a semantic one.
+host CPU device count) is a pure performance choice, never a semantic
+one, and the core runs on the host CPU whatever accelerator is present.
 """
 
 import numpy as np
@@ -156,11 +157,40 @@ def test_jit_policy_env(monkeypatch):
     monkeypatch.setenv("REPRO_KERNEL_JIT", "0")
     assert KS._jit_default() is False
     monkeypatch.delenv("REPRO_KERNEL_JIT")
-    if KS.KERNEL_AVAILABLE:
-        import jax
-        assert KS._jit_default() is (jax.device_count() > 1)
-    else:
+    if not KS.KERNEL_AVAILABLE:
         assert KS._jit_default() is False
+        return
+    import jax
+    assert KS._jit_default() is (len(jax.devices("cpu")) > 1)
+    # a 4-chip TPU host with one host CPU device: the chips do not count,
+    # and the core stays on the host CPU
+    cpu = jax.devices("cpu")[:1]
+    chips = ["tpu"] * 4
+    monkeypatch.setattr(jax, "devices",
+                        lambda backend=None: cpu if backend == "cpu"
+                        else chips)
+    monkeypatch.setattr(jax, "device_count",
+                        lambda backend=None: len(jax.devices(backend)))
+    assert KS._jit_default() is False
+    assert KS._core_device() is cpu[0]
+
+
+@needs_jax
+def test_jit_core_runs_on_the_host_cpu(monkeypatch):
+    topo = T.mesh2d(4, 6)
+    cm = ConflictModel(topo, FULL_DUPLEX)
+    ctl = lower_baseline(topo, cm, "binomial", 0, 64e6)
+    real = KS._CORE
+    seen = []
+
+    def spy(*args):
+        seen.extend(a.devices() for a in args)
+        return real(*args)
+
+    monkeypatch.setattr(KS, "_CORE", spy)
+    ref = CompiledSim(topo, cm, 0).run_lowered(ctl)
+    assert _same(KS.KernelSim(topo, cm, 0).run_lowered(ctl, jit=True), ref)
+    assert seen and all(d == {KS._core_device()} for d in seen)
 
 
 @pytest.mark.parametrize("name", ["binomial", "srda", "pipeline", "glf"])

@@ -1,0 +1,125 @@
+"""Compile rehearsal for a TPU v5e 2x2 host, without the chip.
+
+The TPU compiler is installed with jax and compiles for a chip that is
+described rather than attached, so these tests catch what interpret mode
+and the CPU backend cannot: Mosaic's tiling and VMEM limits, programs that
+do not fit a chip's 16 GB of HBM, and collectives the partitioner inserts.
+Nothing runs; a passing compile says nothing about results or times.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and every test worker
+imports every test file. Keep these tests in this one file.
+"""
+
+import os
+import re
+
+import numpy as np
+import pytest
+
+MIB = 1 << 20
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import jax
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # a compile for a described chip is written to the persistent cache
+    # but cannot be read back without one: keep the cache out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this jax build
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _max_plen(rows: int) -> int:
+    """Longest row (a multiple of 1024) whose buffer the kernel accepts."""
+    from repro.device.pallas_step import VMEM_LIMIT_BYTES, kernel_vmem_bytes
+    plen = 1024
+    while kernel_vmem_bytes(rows, plen + 1024) <= VMEM_LIMIT_BYTES:
+        plen += 1024
+    return plen
+
+
+@pytest.mark.parametrize("shape", ["ladder_1mib", "vmem_budget_edge"])
+def test_pallas_round_step_compiles_for_v5e(one_chip, shape):
+    """The kernel compiles under Mosaic at the torus2d(2,2) 1 MiB plan's
+    packet buffer (6 rows of 43691 f32) and at the largest buffer
+    ``check_kernel_limits`` lets through."""
+    import jax
+    import jax.numpy as jnp
+    from repro.device.pallas_step import _round_step_pallas, check_kernel_limits
+
+    rows, plen = (6, 43691) if shape == "ladder_1mib" else (8, _max_plen(8))
+    check_kernel_limits((rows, plen), jnp.float32)
+    args = (jax.ShapeDtypeStruct((rows, plen), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((plen,), jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct((4,), jnp.int32, sharding=one_chip))
+    compiled = jax.jit(
+        lambda b, r, s: _round_step_pallas(b, r, s, interpret=False)
+    ).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_bbs_broadcast_compiles_for_v5e_2x2(topo):
+    """The whole 4-chip program for torus2d(2,2) at 256 MiB: one
+    collective-permute per sub-round of the cycle, and it fits a chip."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro import api
+    from repro.core import topology as T
+    from repro.device import bbs_broadcast
+
+    nbytes = 256 * MIB
+    ex = api.compile(T.torus2d(2, 2)).executable(0, nbytes)
+    mesh = Mesh(np.array(topo.devices), ("dev",))
+    x = jax.ShapeDtypeStruct((nbytes // 4,), jnp.float32,
+                             sharding=NamedSharding(mesh, P()))
+    compiled = jax.jit(lambda v: bbs_broadcast(
+        v, mesh, "dev", ex.schedule, ex.num_groups)).lower(x).compile()
+    txt = compiled.as_text()
+    permutes = re.findall(r"collective-permute(?:-start)?\(", txt)
+    assert len(permutes) == ex.schedule.d
+    mem = compiled.memory_analysis()
+    per_device = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                  + mem.temp_size_in_bytes)
+    assert per_device < 16e9
+
+
+def test_kernelsim_core_compiles_for_v5e(one_chip):
+    """The float64 event core compiles for the TPU; it runs on the host
+    CPU for exactness, not because XLA:TPU refuses it."""
+    import jax
+    from repro.core import kernelsim as KS
+    from repro.core import topology as T
+    from repro.core.baselines import lower_baseline
+    from repro.core.intersection import FULL_DUPLEX, ConflictModel
+
+    topo16 = T.mesh2d(16, 16)
+    cm = ConflictModel(topo16, FULL_DUPLEX)
+    ks = KS.KernelSim(topo16, cm, 0)
+    ctl = lower_baseline(topo16, cm, "binomial", 0, 64e6)
+    ctl.bind(ks.idx)
+    arrays = (*KS._static_arrays(ctl, ks.idx),
+              np.asarray(ctl.durs, dtype=np.float64))
+    args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+            for a in arrays]
+    compiled = KS._CORE.lower(*args).compile()
+    assert "while" in compiled.as_text()
