@@ -1,0 +1,6 @@
+"""95th percentile over every broadcast in the window of the time from
+calling ``run`` to every chip's copy being ready, in ms."""
+
+
+def read(run):
+    return run.latency_ms(0.95)
