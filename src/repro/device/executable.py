@@ -105,6 +105,13 @@ class ExecutablePlan:
         mesh = mesh or self.mesh()
         return self._runner()(x, mesh)
 
+    def lower(self, x, mesh=None):
+        """The program ``run(x, mesh)`` dispatches, lowered for ``x`` (an
+        array or a ``jax.ShapeDtypeStruct`` with its sharding):
+        ``.compile().as_text()`` is the optimized HLO, whose ``op_name``
+        metadata carries the ``bcast.*`` phase scopes."""
+        return self._runner().lower(x, mesh or self.mesh())
+
     def verify(self, x, mesh=None) -> DeviceDelivery:
         """Run and compare every device's buffer to the payload on raw
         bytes (``verify_delivery`` semantics: no faults => every node of the

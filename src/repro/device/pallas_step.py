@@ -118,7 +118,7 @@ def _round_step_pallas(buf, rec, scal, interpret=False):
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-        name="bbs_round_step",
+        name="bcast_round_step",
     )(scal, buf, rec[None])
     return out, val[0]
 
@@ -126,11 +126,12 @@ def _round_step_pallas(buf, rec, scal, interpret=False):
 def round_step(buf, rec, r_idx, r_ok, s_idx, s_ok, *, use_pallas=False):
     """The packed scatter+gather step: the jnp reference by default, the
     Pallas kernel when ``use_pallas`` (compiled on a TPU, interpreted on
-    the CPU)."""
-    if not use_pallas:
-        return round_step_ref(buf, rec, r_idx, r_ok, s_idx, s_ok)
-    check_kernel_limits(buf.shape, buf.dtype)
-    scal = jnp.stack([jnp.int32(r_idx), jnp.int32(r_ok),
-                      jnp.int32(s_idx), jnp.int32(s_ok)])
-    return _round_step_pallas(buf, rec, scal,
-                              interpret=jax.default_backend() == "cpu")
+    the CPU). Both paths run under the ``bcast.step`` scope."""
+    with jax.named_scope("bcast.step"):
+        if not use_pallas:
+            return round_step_ref(buf, rec, r_idx, r_ok, s_idx, s_ok)
+        check_kernel_limits(buf.shape, buf.dtype)
+        scal = jnp.stack([jnp.int32(r_idx), jnp.int32(r_ok),
+                          jnp.int32(s_idx), jnp.int32(s_ok)])
+        return _round_step_pallas(buf, rec, scal,
+                                  interpret=jax.default_backend() == "cpu")

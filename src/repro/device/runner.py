@@ -15,6 +15,14 @@ initializes** — the device count cannot change afterwards, so tests spawn
 a subprocess, see tests/test_device.py and docs/device.md).
 ``node_coords`` checks that every fabric edge lands on a chip-to-chip
 link.
+
+The three runners name their phases with ``jax.named_scope``, so that a
+profiler trace or the compiled HLO (``op_name`` metadata) tells them apart:
+``bcast.place`` (padding into packet rows, relay rows, zeroing the non-root
+copies), ``bcast.cycle`` (the scan), ``bcast.step`` (the round step, set in
+``repro.device.pallas_step.round_step``) and ``bcast.unstack`` (the
+stacked output cut back to the payload); a collective permute is found by
+its opcode. Scopes are metadata only: the optimized program is the same.
 """
 
 from __future__ import annotations
@@ -88,11 +96,12 @@ def bbs_broadcast(x: jax.Array, mesh: Mesh, axis: str, sched: DeviceSchedule,
     assert n == sched.num_devices
     m = num_groups
     K = sched.K
-    packets, plen = _pad_packets(x, m * K)
     total = m * K
-    if sched.num_relay:
-        packets = jnp.concatenate(
-            [packets, jnp.zeros((sched.num_relay, plen), packets.dtype)])
+    with jax.named_scope("bcast.place"):
+        packets, plen = _pad_packets(x, total)
+        if sched.num_relay:
+            packets = jnp.concatenate(
+                [packets, jnp.zeros((sched.num_relay, plen), packets.dtype)])
     rows = total + sched.num_relay
     send_rel = jnp.asarray(sched.send_rel)
     recv_rel = jnp.asarray(sched.recv_rel)
@@ -103,7 +112,8 @@ def bbs_broadcast(x: jax.Array, mesh: Mesh, axis: str, sched: DeviceSchedule,
 
     def body(buf_x):
         idx = jax.lax.axis_index(axis)
-        buf = jnp.where(idx == sched.root, buf_x, jnp.zeros_like(buf_x))
+        with jax.named_scope("bcast.place"):
+            buf = jnp.where(idx == sched.root, buf_x, jnp.zeros_like(buf_x))
 
         def slot(r, c):
             """(send_idx, send_ok, recv_idx, recv_ok) for sub-round r."""
@@ -136,13 +146,15 @@ def bbs_broadcast(x: jax.Array, mesh: Mesh, axis: str, sched: DeviceSchedule,
                                       use_pallas=use_pallas)
             return buf, ()
 
-        buf, _ = jax.lax.scan(cycle, buf, jnp.arange(num_cycles))
+        with jax.named_scope("bcast.cycle"):
+            buf, _ = jax.lax.scan(cycle, buf, jnp.arange(num_cycles))
         return buf[None]   # leading device axis chunk of size 1
 
     out = jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(axis),
                         check_vma=False)(packets)
-    return out[:, :total].reshape(n, total * plen)[:, :x.size] \
-        .reshape((n,) + x.shape)
+    with jax.named_scope("bcast.unstack"):
+        return out[:, :total].reshape(n, total * plen)[:, :x.size] \
+            .reshape((n,) + x.shape)
 
 
 def binomial_broadcast(x: jax.Array, mesh: Mesh, axis: str,
@@ -155,7 +167,8 @@ def binomial_broadcast(x: jax.Array, mesh: Mesh, axis: str,
     def body(xx):
         idx = jax.lax.axis_index(axis)
         vrank = (idx - root) % n
-        buf = jnp.where(idx == root, xx, jnp.zeros_like(xx))
+        with jax.named_scope("bcast.place"):
+            buf = jnp.where(idx == root, xx, jnp.zeros_like(xx))
         have = (vrank == 0)
         for s in reversed(range(steps)):
             stride = 1 << s
@@ -169,7 +182,8 @@ def binomial_broadcast(x: jax.Array, mesh: Mesh, axis: str,
             is_dst = (vrank % (2 * stride) == stride)
             buf = jnp.where(is_dst, rec, buf)
             have = have | is_dst
-        return buf[None]
+        with jax.named_scope("bcast.unstack"):
+            return buf[None]
 
     return jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(axis),
                          check_vma=False)(x)
@@ -181,14 +195,16 @@ def chain_broadcast(x: jax.Array, mesh: Mesh, axis: str, root: int = 0,
     MPICH 'pipeline' baseline), m + n - 2 ppermute rounds."""
     n = mesh.shape[axis]
     m = num_packets
-    packets, plen = _pad_packets(x, m)
+    with jax.named_scope("bcast.place"):
+        packets, plen = _pad_packets(x, m)
     pairs = [(int((root + i) % n), int((root + i + 1) % n))
              for i in range(n - 1)]
 
     def body(pk):
         idx = jax.lax.axis_index(axis)
         vrank = (idx - root) % n
-        buf = jnp.where(idx == root, pk, jnp.zeros_like(pk))
+        with jax.named_scope("bcast.place"):
+            buf = jnp.where(idx == root, pk, jnp.zeros_like(pk))
 
         def step(buf, s):
             # at step s, rank r forwards packet (s - r) if 0 <= s - r < m
@@ -209,4 +225,5 @@ def chain_broadcast(x: jax.Array, mesh: Mesh, axis: str, root: int = 0,
 
     out = jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(axis),
                         check_vma=False)(packets)
-    return out.reshape(n, m * plen)[:, :x.size].reshape((n,) + x.shape)
+    with jax.named_scope("bcast.unstack"):
+        return out.reshape(n, m * plen)[:, :x.size].reshape((n,) + x.shape)

@@ -411,6 +411,64 @@ def test_executable_nonzero_root_and_pallas():
     """)
 
 
+def test_lower_is_the_program_run_dispatches():
+    """``ExecutablePlan.lower`` lowers the jitted program that ``run``
+    calls: compiled, it delivers what ``run`` delivers, and its optimized
+    HLO carries the round step's scope."""
+    out = run_multidevice("""
+        import json
+        import jax, jax.numpy as jnp, numpy as np
+        from repro import api
+        from repro.core import topology as T
+        ex = api.compile(T.torus2d(2, 2)).executable(0, 4096)
+        mesh = ex.mesh()
+        x = np.arange(1024, dtype=np.float32)
+        compiled = ex.lower(jnp.asarray(x), mesh).compile()
+        got = np.asarray(compiled(jnp.asarray(x)))
+        ran = np.asarray(ex.run(jnp.asarray(x), mesh))
+        print(json.dumps({"same": bool((got == ran).all()),
+                          "delivered": bool((got == x[None]).all()),
+                          "step": "bcast.step" in compiled.as_text()}))
+    """, devices=4)
+    got = json.loads(out.strip().splitlines()[-1])
+    assert got == {"same": True, "delivered": True, "step": True}
+
+
+def test_runners_name_their_phases():
+    """The lowered BBS (jnp and Pallas round step), binomial and chain
+    programs carry the shared phase scopes."""
+    out = run_multidevice("""
+        import json, re
+        import jax, jax.numpy as jnp
+        from repro import api
+        from repro.core import topology as T
+        from repro.device import bbs_broadcast
+        from repro.device.runner import (binomial_broadcast, chain_broadcast,
+                                         device_mesh)
+        mesh = device_mesh(4)
+        ex = api.compile(T.torus2d(2, 2)).executable(0, 4096)
+        x = jnp.arange(1024, dtype=jnp.float32)
+        progs = {
+            "bbs": lambda v: bbs_broadcast(v, mesh, "dev", ex.schedule,
+                                           ex.num_groups),
+            "bbs_pallas": lambda v: bbs_broadcast(
+                v, mesh, "dev", ex.schedule, ex.num_groups,
+                use_pallas=True),
+            "binomial": lambda v: binomial_broadcast(v, mesh, "dev"),
+            "chain": lambda v: chain_broadcast(v, mesh, "dev"),
+        }
+        print(json.dumps({k: sorted(set(re.findall(
+            r"bcast\\.[a-z]+",
+            jax.jit(f).lower(x).as_text(debug_info=True))))
+            for k, f in progs.items()}))
+    """, devices=4)
+    scopes = json.loads(out.strip().splitlines()[-1])
+    shared = ["bcast.place", "bcast.unstack"]
+    assert scopes["binomial"] == scopes["chain"] == shared
+    bbs = sorted(shared + ["bcast.cycle", "bcast.step"])
+    assert scopes["bbs"] == scopes["bbs_pallas"] == bbs
+
+
 @pytest.mark.slow
 def test_calibration_prediction_error_bound():
     """Fitted Hockney constants predict the measured cycle time within the

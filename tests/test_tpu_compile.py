@@ -103,6 +103,58 @@ def test_bbs_broadcast_compiles_for_v5e_2x2(topo):
     assert per_device < 16e9
 
 
+def _instructions(hlo: str):
+    """(name, opcode, op_name) of each instruction of an HLO module's text;
+    the entry computation's root is named first."""
+    out, root = [], None
+    for line in hlo.splitlines():
+        m = re.match(r"^\s*(ROOT\s+)?%(\S+) = .*?\s([a-z][\w-]*)\(", line)
+        if not m:
+            continue
+        name = re.search(r'op_name="([^"]*)"', line)
+        ins = (m.group(2), m.group(3), name.group(1) if name else "")
+        out.append(ins)
+        if m.group(1):
+            root = ins                  # the entry computation prints last
+    return [root] + out
+
+
+def _phase(op_name: str) -> str:
+    inner = [p for p in op_name.split("/") if p.startswith("bcast.")]
+    return inner[-1] if inner else ""
+
+
+def test_bbs_broadcast_phases_are_named_for_v5e_2x2(topo):
+    """The benchmark's broadcast (250 MiB f32 from root 0 on torus2d(2, 2))
+    compiled for a v5e 2x2 keeps its phase scopes in the ``op_name`` of
+    the instructions a trace names: every collective permute under
+    ``bcast.cycle`` and in no inner phase, every row write of the cycle
+    under ``bcast.step``, the pad under ``bcast.place`` and the final
+    slice under ``bcast.unstack``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro import api
+    from repro.core import topology as T
+
+    nbytes = 250 * MIB
+    ex = api.compile(T.torus2d(2, 2, preset="tpu_ici")).executable(0, nbytes)
+    mesh = Mesh(np.array(topo.devices), ("dev",))
+    x = jax.ShapeDtypeStruct((nbytes // 4,), jnp.float32,
+                             sharding=NamedSharding(mesh, P()))
+    root, *ins = _instructions(ex.lower(x, mesh).compile().as_text())
+    permutes = [i for i in ins if i[1].startswith("collective-permute")]
+    assert len(permutes) == 2 * ex.schedule.d          # -start and -done
+    assert {_phase(i[2]) for i in permutes} == {"bcast.cycle"}
+    writes = [i for i in ins if i[1] == "dynamic-update-slice"
+              and "bcast.cycle" in i[2]]
+    assert len(writes) == ex.schedule.d + 1           # one per round step
+    assert {_phase(i[2]) for i in writes} == {"bcast.step"}
+    pads = [i for i in ins if i[1] == "pad"]
+    assert pads and {_phase(i[2]) for i in pads} == {"bcast.place"}
+    assert root[1] == "slice" and _phase(root[2]) == "bcast.unstack"
+
+
 def test_kernelsim_core_compiles_for_v5e(one_chip):
     """The float64 event core compiles for the TPU; it runs on the host
     CPU for exactness, not because XLA:TPU refuses it."""
