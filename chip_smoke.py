@@ -85,6 +85,8 @@ def payload(nbytes: int, seed: int) -> np.ndarray:
 
 def build(model, root: int, nbytes: int, algo: str = "bbs", config=None):
     """Phase b: plan fetch + schedule compile for one (root, size)."""
+    from repro.device.runner import _pad_packets
+
     t0 = time.perf_counter()
     model.plan(root)
     t_plan = time.perf_counter() - t0
@@ -93,11 +95,14 @@ def build(model, root: int, nbytes: int, algo: str = "bbs", config=None):
     t_sched = time.perf_counter() - t0
     s = ex.schedule
     rows = ex.num_groups * s.K + s.num_relay
-    plen = -(-(nbytes // 4) // (ex.num_groups * s.K))
+    row = jax.eval_shape(functools.partial(_pad_packets,
+                                           num_packets=ex.num_groups * s.K),
+                         jax.ShapeDtypeStruct((nbytes // 4,),
+                                              np.float32)).shape[1:]
     step = "pallas" if ex.device.use_pallas else "jnp"
     log(f"plan: algo={algo} step={step} root={root} nbytes={nbytes} "
         f"candidate={ex.candidate} K={s.K} d={s.d} m={ex.num_groups} "
-        f"relay={s.num_relay} buffer={rows}x{plen} "
+        f"relay={s.num_relay} buffer={'x'.join(map(str, (rows,) + row))} "
         f"plan_fetch_s={t_plan!r} schedule_s={t_sched!r}")
     return ex
 
@@ -164,8 +169,8 @@ def drive(buf, packets, steps, use_pallas):
 def replay(buf0: np.ndarray, packets: np.ndarray, steps: np.ndarray):
     """The numpy reference of ``drive``."""
     buf = buf0.copy()
-    acc = np.zeros(buf.shape[1], np.uint32)
-    zero = np.zeros(buf.shape[1], buf.dtype)
+    acc = np.zeros(buf.shape[1:], np.uint32)
+    zero = np.zeros(buf.shape[1:], buf.dtype)
     for r_ix, r_ok, s_ix, s_ok, src in steps.tolist():
         if r_ok:
             buf[r_ix] = packets[src]
@@ -175,13 +180,16 @@ def replay(buf0: np.ndarray, packets: np.ndarray, steps: np.ndarray):
 
 
 def round_step_phase(ex, x: np.ndarray, platform: str, stats: dict) -> None:
-    """Phase c for one plan: every node's tables, jnp and Pallas steps."""
+    """Phase c for one plan: every node's tables, jnp and Pallas steps,
+    over the packet buffer ``bbs_broadcast`` builds (rows of whole
+    tiles, then the relay rows)."""
+    from repro.device.runner import _pad_packets
+
     s = ex.schedule
     total = ex.num_groups * s.K
-    plen = -(-x.size // total)
-    packets = np.zeros((total, plen), np.float32)
-    packets.reshape(-1)[:x.size] = x
-    buf0 = np.concatenate([packets, np.zeros((s.num_relay, plen),
+    packets = np.asarray(_pad_packets(jnp.asarray(x), total))
+    buf0 = np.concatenate([packets, np.zeros((s.num_relay,)
+                                             + packets.shape[1:],
                                              np.float32)])
     packets_d = jax.device_put(packets)
     nodes = [ex.root] + [v for v in range(s.num_devices) if v != ex.root]

@@ -20,6 +20,7 @@ take raises ``ValueError`` (:func:`check_kernel_limits`).
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -35,19 +36,39 @@ VMEM_LIMIT_BYTES = 100 << 20
 # dynamic row index there is refused ("cannot statically prove that index
 # in dimension 0 is a multiple of 8").
 ROW_ITEMSIZE = 4
+# An array's last dimension is tiled in lanes of 128; the dimension before
+# it in sublanes, 8 of 32-bit words and more of narrower ones (a tile is
+# 4 KiB whatever the dtype).
+LANES = 128
+
+
+def sublanes(itemsize: int) -> int:
+    """Rows of one (sublane, lane) tile for a dtype of ``itemsize`` bytes."""
+    return 32 // itemsize
 
 
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
-def kernel_vmem_bytes(rows: int, plen: int, itemsize: int = 4) -> int:
-    """VMEM the kernel stages for a ``(rows, plen)`` buffer: the buffer in
-    and out, each tiled to (8, 128), plus the received and the sent row,
-    each a ``(1, plen)`` block tiled to 128 lanes."""
-    buf = _round_up(rows, 8) * _round_up(plen, 128) * itemsize
-    row = _round_up(plen, 128) * itemsize
-    return 2 * buf + 2 * row
+def _tiled_bytes(shape, itemsize: int) -> int:
+    """Bytes of an array of ``shape`` laid out in whole tiles: the last
+    dimension rounded up to lanes, the one before it (if any) to a tile's
+    sublanes."""
+    *lead, minor = shape
+    words = _round_up(minor, LANES)
+    if lead:
+        words *= math.prod(lead[:-1]) * _round_up(lead[-1],
+                                                  sublanes(itemsize))
+    return words * itemsize
+
+
+def kernel_vmem_bytes(shape, itemsize: int = 4) -> int:
+    """VMEM the kernel stages for a packet buffer of ``shape`` (rows first,
+    then the shape of one row): the buffer in and out, and the received
+    and the sent row, each tiled by the shape of a row."""
+    return 2 * _tiled_bytes(shape, itemsize) \
+        + 2 * _tiled_bytes(shape[1:], itemsize)
 
 
 def check_kernel_limits(shape, dtype) -> None:
@@ -62,11 +83,11 @@ def check_kernel_limits(shape, dtype) -> None:
             f"sublane and cannot be indexed dynamically; the kernel takes "
             f"{ROW_ITEMSIZE}-byte dtypes only (use_pallas=False for "
             f"{dtype})")
-    rows, plen = shape
-    need = kernel_vmem_bytes(rows, plen, dtype.itemsize)
+    need = kernel_vmem_bytes(shape, dtype.itemsize)
     if need > VMEM_LIMIT_BYTES:
+        dims = "x".join(str(d) for d in shape)
         raise ValueError(
-            f"Pallas round step: a {rows}x{plen} {dtype} packet buffer "
+            f"Pallas round step: a {dims} {dtype} packet buffer "
             f"needs {need} bytes of VMEM, over the kernel's VMEM budget of "
             f"VMEM_LIMIT_BYTES={VMEM_LIMIT_BYTES} (use_pallas=False for "
             f"this size)")
@@ -86,7 +107,8 @@ def round_step_ref(buf, rec, r_idx, r_ok, s_idx, s_ok):
 
 
 def _scatter_gather_kernel(scal_ref, buf_ref, rec_ref, out_ref, val_ref):
-    # scal = [r_idx, r_ok, s_idx, s_ok]; rec and val are (1, plen). Aliasing
+    # scal = [r_idx, r_ok, s_idx, s_ok]; rec and val are one row, (1,) + the
+    # row's shape, indexed like a row of the buffer (dimension 0). Aliasing
     # makes the HBM buffer in place, not the VMEM blocks: out is a block of
     # its own, and inside a scan on a v5e it does not start as buf (a root
     # that only sends got back a zeroed buffer), so copy buf first. The
@@ -96,15 +118,15 @@ def _scatter_gather_kernel(scal_ref, buf_ref, rec_ref, out_ref, val_ref):
 
     @pl.when(scal_ref[1] != 0)
     def _write():
-        out_ref[pl.ds(scal_ref[0], 1), :] = rec_ref[...]
+        out_ref[pl.ds(scal_ref[0], 1), ...] = rec_ref[...]
 
-    v = out_ref[pl.ds(scal_ref[2], 1), :]
+    v = out_ref[pl.ds(scal_ref[2], 1), ...]
     val_ref[...] = jnp.where(scal_ref[3] != 0, v, jnp.zeros_like(v))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def _round_step_pallas(buf, rec, scal, interpret=False):
-    # the rows travel as (1, plen) blocks, laid out like a buffer row
+    # the rows travel as (1,) + row-shape blocks, laid out like a buffer row
     out, val = pl.pallas_call(
         _scatter_gather_kernel,
         out_shape=(jax.ShapeDtypeStruct(buf.shape, buf.dtype),

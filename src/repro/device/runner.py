@@ -34,7 +34,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.device.pallas_step import round_step
+from repro.device.pallas_step import LANES, round_step, sublanes
 from repro.device.schedule import _NOSEND, DeviceSchedule
 
 
@@ -74,12 +74,29 @@ def node_coords(topo, devices) -> Dict[int, Tuple[int, ...]]:
     return coords
 
 
-def _pad_packets(x: jax.Array, num_packets: int) -> Tuple[jax.Array, int]:
+def _pad_packets(x: jax.Array, num_packets: int) -> jax.Array:
+    """The payload cut into ``num_packets`` rows, zero-padded at the end.
+
+    A TPU lays an array out in tiles of ``sublanes(itemsize)`` x ``LANES``
+    (4 KiB) over its last two dimensions. Rows of a 2-D ``(rows, plen)``
+    buffer would share their tiles, 8 rows to a tile for 32-bit dtypes, so
+    a row access would move the tiles of 8 rows, and the flat payload
+    would have to be interleaved into them. Each row is therefore rounded
+    up to whole tiles and shaped ``(plen / LANES, LANES)``: a row is its
+    own tiles, and padding the flat payload into rows and taking them back
+    out are bitcasts."""
     flat = x.reshape(-1)
-    plen = -(-flat.size // num_packets)
-    pad = plen * num_packets - flat.size
-    flat = jnp.pad(flat, (0, pad))
-    return flat.reshape(num_packets, plen), plen
+    tile = sublanes(flat.dtype.itemsize) * LANES
+    plen = -(-flat.size // (num_packets * tile)) * tile
+    flat = jnp.pad(flat, (0, plen * num_packets - flat.size))
+    return flat.reshape(num_packets, plen // LANES, LANES)
+
+
+def _unstack(out: jax.Array, x: jax.Array) -> jax.Array:
+    """The stacked ``(n, rows, *row)`` buffers cut back to ``(n,) +
+    x.shape``: the payload is the leading ``x.size`` words of each."""
+    n = out.shape[0]
+    return out.reshape(n, -1)[:, :x.size].reshape((n,) + x.shape)
 
 
 def bbs_broadcast(x: jax.Array, mesh: Mesh, axis: str, sched: DeviceSchedule,
@@ -98,11 +115,11 @@ def bbs_broadcast(x: jax.Array, mesh: Mesh, axis: str, sched: DeviceSchedule,
     K = sched.K
     total = m * K
     with jax.named_scope("bcast.place"):
-        packets, plen = _pad_packets(x, total)
+        packets = _pad_packets(x, total)
         if sched.num_relay:
             packets = jnp.concatenate(
-                [packets, jnp.zeros((sched.num_relay, plen), packets.dtype)])
-    rows = total + sched.num_relay
+                [packets, jnp.zeros((sched.num_relay,) + packets.shape[1:],
+                                    packets.dtype)])
     send_rel = jnp.asarray(sched.send_rel)
     recv_rel = jnp.asarray(sched.recv_rel)
     send_abs = jnp.asarray(sched.send_abs)
@@ -132,7 +149,7 @@ def bbs_broadcast(x: jax.Array, mesh: Mesh, axis: str, sched: DeviceSchedule,
 
         def cycle(buf, c):
             s_ix, s_ok, _, _ = slot(0, c)
-            zero = jnp.zeros((plen,), buf.dtype)
+            zero = jnp.zeros(buf.shape[1:], buf.dtype)
             buf, val = round_step(buf, zero, 0, False, s_ix, s_ok,
                                   use_pallas=use_pallas)
             for r in range(sched.d):
@@ -153,8 +170,7 @@ def bbs_broadcast(x: jax.Array, mesh: Mesh, axis: str, sched: DeviceSchedule,
     out = jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(axis),
                         check_vma=False)(packets)
     with jax.named_scope("bcast.unstack"):
-        return out[:, :total].reshape(n, total * plen)[:, :x.size] \
-            .reshape((n,) + x.shape)
+        return _unstack(out, x)
 
 
 def binomial_broadcast(x: jax.Array, mesh: Mesh, axis: str,
@@ -196,7 +212,7 @@ def chain_broadcast(x: jax.Array, mesh: Mesh, axis: str, root: int = 0,
     n = mesh.shape[axis]
     m = num_packets
     with jax.named_scope("bcast.place"):
-        packets, plen = _pad_packets(x, m)
+        packets = _pad_packets(x, m)
     pairs = [(int((root + i) % n), int((root + i + 1) % n))
              for i in range(n - 1)]
 
@@ -211,7 +227,7 @@ def chain_broadcast(x: jax.Array, mesh: Mesh, axis: str, root: int = 0,
             p = s - vrank
             ok = (p >= 0) & (p < m) & (vrank < n - 1)
             safe = jnp.clip(p, 0, m - 1)
-            val = jnp.where(ok, buf[safe], jnp.zeros((plen,), buf.dtype))
+            val = jnp.where(ok, buf[safe], jnp.zeros(buf.shape[1:], buf.dtype))
             rec = jax.lax.ppermute(val, axis, pairs)
             pr = s - vrank + 1
             rok = (pr >= 0) & (pr < m) & (vrank >= 1)
@@ -226,4 +242,4 @@ def chain_broadcast(x: jax.Array, mesh: Mesh, axis: str, root: int = 0,
     out = jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(axis),
                         check_vma=False)(packets)
     with jax.named_scope("bcast.unstack"):
-        return out.reshape(n, m * plen)[:, :x.size].reshape((n,) + x.shape)
+        return _unstack(out, x)

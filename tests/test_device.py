@@ -147,6 +147,26 @@ def test_pallas_round_step_matches_oracle():
         np.testing.assert_array_equal(np.asarray(v0), np.asarray(v1))
 
 
+@pytest.mark.parametrize("r_ok", [True, False])
+@pytest.mark.parametrize("s_ok", [True, False])
+def test_pallas_round_step_matches_oracle_on_tiled_rows(r_ok, s_ok):
+    """A buffer whose rows are (P, 128) tiles, as ``_pad_packets`` shapes
+    long rows: the kernel indexes dimension 0 with the row whole."""
+    import jax.numpy as jnp
+    from repro.device.pallas_step import round_step, round_step_ref
+
+    rng = np.random.RandomState(1)
+    buf = jnp.asarray(rng.rand(6, 16, 128).astype(np.float32))
+    rec = jnp.asarray(rng.rand(16, 128).astype(np.float32))
+    for r_idx, s_idx in [(2, 4), (5, 5), (0, 3)]:
+        b0, v0 = round_step_ref(buf, rec, r_idx, r_ok, s_idx, s_ok)
+        b1, v1 = round_step(buf, rec, r_idx, r_ok, s_idx, s_ok,
+                            use_pallas=True)
+        assert b1.shape == buf.shape and v1.shape == rec.shape
+        np.testing.assert_array_equal(np.asarray(b0), np.asarray(b1))
+        np.testing.assert_array_equal(np.asarray(v0), np.asarray(v1))
+
+
 @pytest.mark.parametrize("dtype", ["bfloat16", "float16", "int8", "uint8"])
 def test_pallas_refuses_packed_row_dtypes(dtype):
     """Rows narrower than 32 bits cannot be indexed dynamically by Mosaic:
@@ -174,13 +194,49 @@ def test_pallas_refuses_buffers_past_the_vmem_budget():
     rows = 8
     plen = VMEM_LIMIT_BYTES // (2 * 4 * (rows + 1))   # just over the edge
     plen += 1024
-    assert kernel_vmem_bytes(rows, plen) > VMEM_LIMIT_BYTES
-    with pytest.raises(ValueError, match="VMEM_LIMIT_BYTES"):
-        jax.eval_shape(step, jax.ShapeDtypeStruct((rows, plen), jnp.float32))
+    for row in [(plen,), (plen // 128, 128)]:
+        assert kernel_vmem_bytes((rows,) + row) > VMEM_LIMIT_BYTES
+        with pytest.raises(ValueError, match="VMEM_LIMIT_BYTES"):
+            jax.eval_shape(step, jax.ShapeDtypeStruct((rows,) + row,
+                                                      jnp.float32))
     plen -= 2048
-    assert kernel_vmem_bytes(rows, plen) <= VMEM_LIMIT_BYTES
-    check_kernel_limits((rows, plen), jnp.float32)
-    jax.eval_shape(step, jax.ShapeDtypeStruct((rows, plen), jnp.float32))
+    for row in [(plen,), (plen // 128, 128)]:
+        assert kernel_vmem_bytes((rows,) + row) <= VMEM_LIMIT_BYTES
+        check_kernel_limits((rows,) + row, jnp.float32)
+        jax.eval_shape(step, jax.ShapeDtypeStruct((rows,) + row,
+                                                  jnp.float32))
+
+
+def test_kernel_vmem_counts_each_row_shapes_own_tiles():
+    """A 2-D buffer's rows share (8, 128) tiles; a (P, 128) row is tiled
+    on its own, so a row of 9 sublanes takes 16."""
+    from repro.device.pallas_step import kernel_vmem_bytes
+
+    assert kernel_vmem_bytes((3, 200)) == 4 * (2 * 8 * 256 + 2 * 256)
+    assert kernel_vmem_bytes((3, 9, 128)) == \
+        4 * (2 * 3 * 16 * 128 + 2 * 16 * 128)
+    assert kernel_vmem_bytes((3, 9, 128), itemsize=2) == \
+        2 * (2 * 3 * 16 * 128 + 2 * 16 * 128)
+
+
+@pytest.mark.parametrize("dtype, tile", [("float32", 1024),
+                                         ("bfloat16", 2048)])
+def test_pad_packets_rounds_rows_to_whole_tiles(dtype, tile):
+    """Every row is rounded up to whole (sublane, 128) tiles and shaped
+    (P, 128), from a row of one word to one just past 64 tiles. The
+    payload leads the buffer, zeros follow."""
+    import jax.numpy as jnp
+    from repro.device.runner import _pad_packets
+
+    rows = 3
+    for plen, tiles in [(1, 1), (tile - 1, 1), (tile, 1), (tile + 1, 2),
+                        (64 * tile + 1, 65)]:
+        x = jnp.arange(rows * plen - 1).astype(dtype)
+        buf = _pad_packets(x, rows)
+        assert buf.shape == (rows, tiles * tile // 128, 128)
+        flat = np.asarray(buf).reshape(-1)
+        np.testing.assert_array_equal(flat[:x.size], np.asarray(x))
+        assert not flat[x.size:].any()
 
 
 # ---------------------------------------------------------------------------
@@ -432,6 +488,61 @@ def test_lower_is_the_program_run_dispatches():
     """, devices=4)
     got = json.loads(out.strip().splitlines()[-1])
     assert got == {"same": True, "delivered": True, "step": True}
+
+
+# a payload whose rows (12 of 87,357 f32 on torus2d(2, 2)) span many
+# tiles and are not a whole number of them, and one whose rows are shorter
+# than a tile
+ROW_SIZES = {"multi_tile": (1 << 20) - 300, "sub_tile": 1000}
+
+
+@pytest.mark.parametrize("size", sorted(ROW_SIZES))
+def test_broadcasts_deliver_bit_exact_on_tiled_rows(size):
+    """BBS on torus2d(2, 2) and the chain baseline (8 packets), which
+    share ``_pad_packets``, deliver the payload bit for bit from roots 0
+    and 3; the BBS program's packet buffer has the row shape
+    ``_pad_packets`` chose, whole (8, 128) tiles."""
+    out = run_multidevice(f"""
+        import json
+        import jax.numpy as jnp, numpy as np
+        from repro import api
+        from repro.core import topology as T
+        from repro.device.runner import (_pad_packets, chain_broadcast,
+                                         device_mesh)
+        words = {ROW_SIZES[size]}
+        x = np.random.RandomState(5).rand(words).astype(np.float32)
+        model = api.compile(T.torus2d(2, 2))
+        mesh = device_mesh(4)
+        xc = x[:8 * words // 12]
+        got = {{"chain_row": list(_pad_packets(jnp.asarray(xc), 8).shape[1:])}}
+        for root in (0, 3):
+            ex = model.executable(root, 4 * words)
+            rows = ex.num_groups * ex.schedule.K
+            shape = _pad_packets(jnp.asarray(x), rows).shape
+            txt = ex.lower(jnp.asarray(x)).as_text()
+            chain = np.asarray(chain_broadcast(jnp.asarray(xc), mesh, "dev",
+                                               root=root))
+            got[root] = {{"ok": ex.verify(x).ok, "rows": rows,
+                         "row": list(shape[1:]),
+                         "in_program": "x".join(map(str, shape)) + "xf32"
+                                       in txt,
+                         "chain": [chain[v].tobytes() == xc.tobytes()
+                                   for v in range(4)]}}
+        print(json.dumps(got))
+    """, devices=4)
+    got = json.loads(out.strip().splitlines()[-1])
+    words = ROW_SIZES[size]
+    for root in ("0", "3"):
+        assert got[root]["ok"] and got[root]["in_program"], got
+        assert got[root]["chain"] == [True] * 4, got
+        p, lanes = got[root]["row"]
+        assert lanes == 128 and p % 8 == 0
+        plen = -(-words // got[root]["rows"])
+        assert plen <= p * 128 < plen + 1024
+        if size == "sub_tile":
+            assert p == 8
+    p, lanes = got["chain_row"]
+    assert lanes == 128 and p % 8 == 0
 
 
 def test_runners_name_their_phases():

@@ -52,24 +52,29 @@ def _max_plen(rows: int) -> int:
     """Longest row (a multiple of 1024) whose buffer the kernel accepts."""
     from repro.device.pallas_step import VMEM_LIMIT_BYTES, kernel_vmem_bytes
     plen = 1024
-    while kernel_vmem_bytes(rows, plen + 1024) <= VMEM_LIMIT_BYTES:
+    while kernel_vmem_bytes((rows, plen + 1024)) <= VMEM_LIMIT_BYTES:
         plen += 1024
     return plen
 
 
-@pytest.mark.parametrize("shape", ["ladder_1mib", "vmem_budget_edge"])
+@pytest.mark.parametrize("shape", ["ladder_1mib", "vmem_budget_edge",
+                                   "tiled_rows", "ladder_1mib_tiled"])
 def test_pallas_round_step_compiles_for_v5e(one_chip, shape):
     """The kernel compiles under Mosaic at the torus2d(2,2) 1 MiB plan's
-    packet buffer (6 rows of 43691 f32) and at the largest buffer
-    ``check_kernel_limits`` lets through."""
+    packet buffer (6 rows of 43691 f32), at the largest buffer
+    ``check_kernel_limits`` lets through, and on rows of whole (8, 128)
+    tiles, as ``bbs_broadcast`` lays them out: 8 rows of the 250 MiB
+    plan's (5024, 128) and the 1 MiB plan's 6 rows of (344, 128)."""
     import jax
     import jax.numpy as jnp
     from repro.device.pallas_step import _round_step_pallas, check_kernel_limits
 
-    rows, plen = (6, 43691) if shape == "ladder_1mib" else (8, _max_plen(8))
-    check_kernel_limits((rows, plen), jnp.float32)
-    args = (jax.ShapeDtypeStruct((rows, plen), jnp.float32, sharding=one_chip),
-            jax.ShapeDtypeStruct((plen,), jnp.float32, sharding=one_chip),
+    buf = {"ladder_1mib": (6, 43691), "vmem_budget_edge": (8, _max_plen(8)),
+           "tiled_rows": (8, 5024, 128),
+           "ladder_1mib_tiled": (6, 344, 128)}[shape]
+    check_kernel_limits(buf, jnp.float32)
+    args = (jax.ShapeDtypeStruct(buf, jnp.float32, sharding=one_chip),
+            jax.ShapeDtypeStruct(buf[1:], jnp.float32, sharding=one_chip),
             jax.ShapeDtypeStruct((4,), jnp.int32, sharding=one_chip))
     compiled = jax.jit(
         lambda b, r, s: _round_step_pallas(b, r, s, interpret=False)
@@ -119,6 +124,12 @@ def _instructions(hlo: str):
     return [root] + out
 
 
+def _entry(hlo: str) -> str:
+    """The text of an HLO module's entry computation."""
+    start = hlo.index("\nENTRY ")
+    return hlo[start:hlo.index("\n}", start)]
+
+
 def _phase(op_name: str) -> str:
     inner = [p for p in op_name.split("/") if p.startswith("bcast.")]
     return inner[-1] if inner else ""
@@ -130,7 +141,10 @@ def test_bbs_broadcast_phases_are_named_for_v5e_2x2(topo):
     the instructions a trace names: every collective permute under
     ``bcast.cycle`` and in no inner phase, every row write of the cycle
     under ``bcast.step``, the pad under ``bcast.place`` and the final
-    slice under ``bcast.unstack``."""
+    slice under ``bcast.unstack``. Its packet rows are whole (8, 128)
+    tiles, so the flat payload goes into and out of the buffer by bitcasts:
+    the entry holds the cycle's loop and no relayout loop, and the
+    temporaries come to about one payload."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -142,7 +156,9 @@ def test_bbs_broadcast_phases_are_named_for_v5e_2x2(topo):
     mesh = Mesh(np.array(topo.devices), ("dev",))
     x = jax.ShapeDtypeStruct((nbytes // 4,), jnp.float32,
                              sharding=NamedSharding(mesh, P()))
-    root, *ins = _instructions(ex.lower(x, mesh).compile().as_text())
+    compiled = ex.lower(x, mesh).compile()
+    hlo = compiled.as_text()
+    root, *ins = _instructions(hlo)
     permutes = [i for i in ins if i[1].startswith("collective-permute")]
     assert len(permutes) == 2 * ex.schedule.d          # -start and -done
     assert {_phase(i[2]) for i in permutes} == {"bcast.cycle"}
@@ -153,6 +169,10 @@ def test_bbs_broadcast_phases_are_named_for_v5e_2x2(topo):
     pads = [i for i in ins if i[1] == "pad"]
     assert pads and {_phase(i[2]) for i in pads} == {"bcast.place"}
     assert root[1] == "slice" and _phase(root[2]) == "bcast.unstack"
+    assert len(re.findall(r" while\(", _entry(hlo))) == 1
+    rows = ex.num_groups * ex.schedule.K + ex.schedule.num_relay
+    assert rows == 102 and "f32[102,5024,128]" in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1.1 * nbytes
 
 
 def test_kernelsim_core_compiles_for_v5e(one_chip):
