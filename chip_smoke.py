@@ -11,8 +11,8 @@ in-memory ``PlanServer``, never from files on disk.
     python3 chip_smoke.py             # one chip: phases a-d
     python3 chip_smoke.py --chips 4   # a 2x2 host: the broadcast only
 
-One chip cannot run a 4-node broadcast (one device per fabric node), so the
-default run covers what one chip holds:
+The broadcast between chips runs with ``--chips 4``; the default run covers
+what one chip holds:
 
   a. the device; exits non-zero unless JAX's platform is ``tpu``;
   b. for each ladder size and root, the ``ExecutablePlan``: candidate, K,

@@ -55,8 +55,9 @@ class DeviceConfig:
     """Device-execution options (the ``SimConfig.device`` block).
 
     ``mesh_shape`` is the jax device mesh shape (default: one flat axis over
-    ``topo.num_nodes`` devices — the only layout ``ExecutablePlan`` runs
-    today; multi-axis shapes must still multiply out to the node count).
+    as many devices as evenly divide ``topo.num_nodes``; the runner folds
+    the fabric's nodes onto a flat axis over ``prod(mesh_shape)`` devices,
+    which must divide the node count).
     ``dtype`` is the payload dtype the runner is compiled for; ``emulate``
     documents that the mesh is host-emulated (``XLA_FLAGS=
     --xla_force_host_platform_device_count=N`` before jax initializes) so

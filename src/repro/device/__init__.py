@@ -16,14 +16,15 @@ from repro.device.executable import (DeviceDelivery, ExecutablePlan,
                                      build_executable)
 from repro.device.runner import (bbs_broadcast, binomial_broadcast,
                                  chain_broadcast, device_mesh, node_coords)
-from repro.device.schedule import (DeviceSchedule, NotDeviceExecutable,
-                                   make_device_schedule)
+from repro.device.schedule import (DeviceSchedule, FoldedSchedule,
+                                   NotDeviceExecutable, far_pairs,
+                                   fold_schedule, make_device_schedule)
 
 __all__ = [
     "CalibratedCost", "PredictionRow", "apply_calibration", "calibrate",
     "measure_round", "predict_cycle_time", "prediction_report",
     "DeviceDelivery", "ExecutablePlan", "build_executable",
     "bbs_broadcast", "binomial_broadcast", "chain_broadcast", "device_mesh",
-    "node_coords", "DeviceSchedule", "NotDeviceExecutable",
-    "make_device_schedule",
+    "far_pairs", "node_coords", "DeviceSchedule", "FoldedSchedule",
+    "NotDeviceExecutable", "fold_schedule", "make_device_schedule",
 ]

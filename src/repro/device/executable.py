@@ -43,7 +43,7 @@ from repro.device.schedule import (DeviceSchedule, NotDeviceExecutable,
 class DeviceDelivery:
     """Bit-exact delivery check (the device rendering of
     ``repro.core.faults.DeliveryCheck``): with no faults every node is
-    required; ``missing`` lists devices whose buffer differs from the
+    required; ``missing`` lists nodes whose buffer differs from the
     payload."""
 
     ok: bool
@@ -72,17 +72,30 @@ class ExecutablePlan:
 
     # -- runners -------------------------------------------------------------
 
-    def mesh(self):
-        """The execution mesh from the device block (flat axis over the
-        fabric's node count unless ``mesh_shape`` overrides it)."""
+    def mesh(self, devices=None):
+        """The execution mesh: one flat axis over ``devices``, or by
+        default over the first ``mesh_shape`` devices, else over as many
+        of ``jax.devices()`` as evenly divide the fabric's node count (one
+        node per device where there are enough). The fabric's nodes fold
+        onto the mesh in contiguous blocks (``fold_schedule``)."""
+        import jax
+        from jax.sharding import Mesh
         from repro.device.runner import device_mesh
-        shape = self.device.mesh_shape or (self.topo.num_nodes,)
-        n = int(np.prod(shape))
-        if n != self.topo.num_nodes:
+        n = self.topo.num_nodes
+        if devices is not None:
+            k = len(devices)
+        elif self.device.mesh_shape:
+            k = int(np.prod(self.device.mesh_shape))
+        else:
+            have = len(jax.devices())
+            k = max(d for d in range(1, min(have, n) + 1) if n % d == 0)
+        if n % k:
             raise ValueError(
-                f"device mesh shape {shape} has {n} devices; the fabric "
-                f"has {self.topo.num_nodes} nodes")
-        return device_mesh(n, axis=self.device.axis)
+                f"a mesh of {k} devices cannot hold the fabric's {n} nodes "
+                f"in equal blocks")
+        if devices is None:
+            return device_mesh(k, axis=self.device.axis)
+        return Mesh(np.array(devices), (self.device.axis,))
 
     def _runner(self):
         import jax
@@ -100,8 +113,9 @@ class ExecutablePlan:
         return fn
 
     def run(self, x, mesh=None):
-        """Execute the broadcast; returns the per-device copies stacked on a
-        leading axis (shape ``(n,) + x.shape``)."""
+        """Execute the broadcast; returns every fabric node's copy stacked
+        on a leading axis (shape ``(num_nodes,) + x.shape``), whatever the
+        number of devices the nodes are folded onto."""
         mesh = mesh or self.mesh()
         return self._runner()(x, mesh)
 
@@ -113,15 +127,15 @@ class ExecutablePlan:
         return self._runner().lower(x, mesh or self.mesh())
 
     def verify(self, x, mesh=None) -> DeviceDelivery:
-        """Run and compare every device's buffer to the payload on raw
-        bytes (``verify_delivery`` semantics: no faults => every node of the
+        """Run and compare every node's buffer to the payload on raw bytes
+        (``verify_delivery`` semantics: no faults => every node of the
         fabric must hold the complete message bit-identically)."""
         import jax.numpy as jnp
         # non-destructive: the runner donates its payload, so run a copy
         # and keep the caller's array (and our reference bytes) intact
         ref = np.asarray(x).copy()
         out = np.asarray(self.run(jnp.asarray(ref.copy()), mesh))
-        required = tuple(range(self.schedule.num_devices))
+        required = tuple(range(self.topo.num_nodes))
         missing = tuple(v for v in required
                         if out[v].tobytes() != ref.tobytes())
         return DeviceDelivery(ok=not missing, required=required,

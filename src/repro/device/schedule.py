@@ -23,6 +23,12 @@ Two things the seed compiler did not do:
     index (no ``cycle*K`` shift) — so topology-oblivious trees (Bine,
     binomial-over-ranks) run on sparse fabrics through the same tables.
     Every hop is validated to be a physical cable.
+
+A fabric may have more nodes than there are devices: ``fold_schedule``
+puts its nodes on the devices in contiguous blocks (node ``v`` on device
+``v // nodes_per_chip``) and splits each sub-round's pairs into moves
+between nodes of one chip and pairs between chips, the latter grouped
+into chip-level permutations that carry several stacked rows.
 """
 
 from __future__ import annotations
@@ -245,3 +251,190 @@ def make_device_schedule(pipe: Pipeline, num_devices: int,
                           send_rel=send_rel, recv_rel=recv_rel,
                           send_abs=send_abs, recv_abs=recv_abs,
                           num_relay=num_relay, root=root)
+
+
+@dataclasses.dataclass
+class RowTable:
+    """The rows that one group of a sub-round reads or writes on each chip:
+    ``width`` entries per chip, each a node's slot on the chip and the row
+    in ``DeviceSchedule``'s convention (``rel``: packet index relative to
+    the cycle, ``_NOSEND`` where the entry pads the group; ``abs``: relay
+    slot, -1 for a packet row)."""
+
+    slot: np.ndarray            # (chips, width) int64
+    rel: np.ndarray             # (chips, width) int64
+    abs: np.ndarray             # (chips, width) int64
+
+    @property
+    def width(self) -> int:
+        return self.slot.shape[1]
+
+    def active(self, num_groups: int, K: int, num_cycles: int) -> np.ndarray:
+        """Per chip, the entries that hold a row over a whole broadcast
+        (summed over its cycles); the runner masks the others."""
+        pk = np.arange(num_cycles)[:, None, None] * K + self.rel[None]
+        ok = (self.abs[None] >= 0) | ((self.rel[None] != _NOSEND)
+                                      & (pk >= 0) & (pk < num_groups * K))
+        return ok.sum(axis=(0, 2))
+
+
+@dataclasses.dataclass
+class ChipPermute:
+    """One chip-level permutation of a sub-round: ``pairs`` of (source
+    chip, target chip), each chip at most once on either side; row ``i`` of
+    the stack that a source reads (``send``) is written on its target by
+    entry ``i`` of ``recv``."""
+
+    pairs: List[Tuple[int, int]]
+    send: RowTable
+    recv: RowTable
+    node_pairs: List[Tuple[int, int]]   # the fabric pairs it carries
+
+
+@dataclasses.dataclass
+class FoldRound:
+    """One sub-round on the chips: the moves between nodes of one chip
+    (``local_send`` row ``i`` goes to ``local_recv`` row ``i``), and the
+    chip-level permutations of the pairs between chips."""
+
+    local_send: RowTable
+    local_recv: RowTable
+    permutes: List[ChipPermute]
+
+
+@dataclasses.dataclass
+class FoldedSchedule:
+    """A ``DeviceSchedule`` folded onto ``chips`` devices: node ``v`` on
+    chip ``v // nodes_per_chip`` in slot ``v % nodes_per_chip``. With one
+    node per chip there are no local moves and each sub-round is one
+    permutation of single rows, the schedule's own matching."""
+
+    schedule: DeviceSchedule
+    chips: int
+    nodes_per_chip: int
+    rounds: List[FoldRound]
+
+    def counters(self, num_groups: int,
+                 coords: Optional[Sequence[Tuple[int, ...]]] = None) -> dict:
+        """What one broadcast of ``num_groups`` packet groups moves: rows
+        handed between nodes of one chip (``rows_local``) and sent between
+        chips (``rows_cross``), masked padding left out; collective
+        permutes (``permutes``) and the rows they stack, padding included
+        (``permute_rows``); per chip, the rows its nodes read to send and
+        write on receipt (``rows_sent``, ``rows_received``). With the
+        chips' ``coords``, ``far_pairs`` counts the pairs of the cycle
+        whose chips are not neighbours (their rows cross two links)."""
+        s = self.schedule
+        cycles = s.num_cycles(num_groups)
+        act = lambda t: t.active(num_groups, s.K, cycles)  # noqa: E731
+        sent = np.zeros(self.chips, np.int64)
+        received = np.zeros(self.chips, np.int64)
+        out = {"rows_local": 0, "rows_cross": 0, "permutes": 0,
+               "permute_rows": 0}
+        for rnd in self.rounds:
+            sent += act(rnd.local_send)
+            received += act(rnd.local_recv)
+            out["rows_local"] += int(act(rnd.local_recv).sum())
+            for p in rnd.permutes:
+                sent += act(p.send)
+                received += act(p.recv)
+                out["rows_cross"] += int(act(p.recv).sum())
+                out["permutes"] += cycles
+                out["permute_rows"] += cycles * p.send.width
+        out["rows_sent"] = sent.tolist()
+        out["rows_received"] = received.tolist()
+        if coords is not None:
+            at = {v: coords[v // self.nodes_per_chip]
+                  for v in range(s.num_devices)}
+            out["far_pairs"] = sum(
+                len(far_pairs(p.node_pairs, at))
+                for rnd in self.rounds for p in rnd.permutes)
+        return out
+
+
+def far_pairs(pairs, coords) -> List[Tuple[int, int]]:
+    """The node pairs whose chips are not neighbours (``coords`` of each
+    node's chip more than one step apart): a collective permute carries
+    their rows across an intermediate chip, not over one link."""
+    return [(u, v) for u, v in pairs
+            if sum(abs(a - b) for a, b in zip(coords[u], coords[v])) > 1]
+
+
+def _row_table(sched: DeviceSchedule, r: int, per: int,
+               entries: List[List[Tuple[int, str]]], width: int) -> RowTable:
+    """Per chip, the rows of ``entries`` ((node, "send" | "recv") in
+    order), padded to ``width`` with entries that never hold a row."""
+    chips = len(entries)
+    slot = np.zeros((chips, width), np.int64)
+    rel = np.full((chips, width), _NOSEND, np.int64)
+    ab = np.full((chips, width), -1, np.int64)
+    for c, row in enumerate(entries):
+        for i, (v, side) in enumerate(row):
+            slot[c, i] = v % per
+            rel[c, i] = (sched.send_rel if side == "send"
+                         else sched.recv_rel)[r][v]
+            ab[c, i] = (sched.send_abs if side == "send"
+                        else sched.recv_abs)[r][v]
+    return RowTable(slot, rel, ab)
+
+
+def _matchings(weights: Dict[Tuple[int, int], int]
+               ) -> List[List[Tuple[int, int]]]:
+    """The ordered chip pairs split into matchings (each chip sends to one
+    chip and receives from one chip at most): heaviest first, each into the
+    first matching it fits, ties in the order given."""
+    out: List[List[Tuple[int, int]]] = []
+    for pair in sorted(weights, key=lambda p: -weights[p]):
+        for m in out:
+            if all(pair[0] != a and pair[1] != b for a, b in m):
+                m.append(pair)
+                break
+        else:
+            out.append([pair])
+    return out
+
+
+def fold_schedule(sched: DeviceSchedule, chips: int) -> FoldedSchedule:
+    """Fold the schedule's nodes onto ``chips`` devices in contiguous
+    blocks. Each sub-round's pairs split into moves inside a chip and pairs
+    between chips; the latter are grouped by ordered chip pair (the rows of
+    one chip pair travel stacked, in the schedule's pair order) and the
+    chip pairs are split into matchings, one collective permute each."""
+    n = sched.num_devices
+    if chips < 1 or n % chips:
+        raise ValueError(f"{n} fabric nodes cannot be folded onto {chips} "
+                         f"devices in equal blocks")
+    per = n // chips
+    rounds = []
+    for r in range(sched.d):
+        local: List[List[Tuple[int, int]]] = [[] for _ in range(chips)]
+        cross: Dict[Tuple[int, int], List[Tuple[int, int]]] = {}
+        for u, v in sched.perms[r]:
+            a, b = u // per, v // per
+            if a == b:
+                local[a].append((u, v))
+            else:
+                cross.setdefault((a, b), []).append((u, v))
+        width = max(len(x) for x in local)
+        permutes = []
+        for pairs in _matchings({k: len(v) for k, v in cross.items()}):
+            w = max(len(cross[p]) for p in pairs)
+            send = [[] for _ in range(chips)]
+            recv = [[] for _ in range(chips)]
+            for a, b in pairs:
+                send[a] = [(u, "send") for u, _ in cross[(a, b)]]
+                recv[b] = [(v, "recv") for _, v in cross[(a, b)]]
+            permutes.append(ChipPermute(
+                pairs=pairs, send=_row_table(sched, r, per, send, w),
+                recv=_row_table(sched, r, per, recv, w),
+                node_pairs=[uv for p in pairs for uv in cross[p]]))
+        rounds.append(FoldRound(
+            local_send=_row_table(sched, r, per,
+                                  [[(u, "send") for u, _ in x]
+                                   for x in local], width),
+            local_recv=_row_table(sched, r, per,
+                                  [[(v, "recv") for _, v in x]
+                                   for x in local], width),
+            permutes=permutes))
+    return FoldedSchedule(schedule=sched, chips=chips, nodes_per_chip=per,
+                          rounds=rounds)

@@ -261,11 +261,20 @@ def test_torus2d_edges_land_on_chip_links():
 
 
 def test_ring4_has_edges_between_diagonal_chips():
+    """Two of ring(4)'s four edges join diagonal chips: ``far_pairs``
+    names them (both directions) and ``node_coords`` does not refuse
+    them; torus2d(2, 2) has none."""
     from repro.core import topology as T
-    from repro.device import node_coords
+    from repro.device import far_pairs, node_coords
 
-    with pytest.raises(ValueError, match="share no link"):
-        node_coords(T.ring(4), V5E_2X2)
+    topo = T.ring(4)
+    far = far_pairs(topo.candidate_edges, node_coords(topo, V5E_2X2))
+    assert sorted(far) == [(0, 3), (1, 2), (2, 1), (3, 0)]
+    torus = T.torus2d(2, 2)
+    assert far_pairs(torus.candidate_edges,
+                     node_coords(torus, V5E_2X2)) == []
+    with pytest.raises(ValueError, match="equal blocks"):
+        node_coords(T.ring(6), V5E_2X2)
 
 
 def test_device_mesh_error_names_both_counts():

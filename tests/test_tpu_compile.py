@@ -144,12 +144,16 @@ def test_bbs_broadcast_phases_are_named_for_v5e_2x2(topo):
     slice under ``bcast.unstack``. Its packet rows are whole (8, 128)
     tiles, so the flat payload goes into and out of the buffer by bitcasts:
     the entry holds the cycle's loop and no relayout loop, and the
-    temporaries come to about one payload."""
+    temporaries come to about one payload. With one fabric node per chip
+    the fold hands nothing over inside a chip: no op under
+    ``bcast.local``, and 104 collective permutes a broadcast (one per
+    sub-round, ``d`` in the cycle's body)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     from repro import api
     from repro.core import topology as T
+    from repro.device import fold_schedule
 
     nbytes = 250 * MIB
     ex = api.compile(T.torus2d(2, 2, preset="tpu_ici")).executable(0, nbytes)
@@ -166,6 +170,9 @@ def test_bbs_broadcast_phases_are_named_for_v5e_2x2(topo):
               and "bcast.cycle" in i[2]]
     assert len(writes) == ex.schedule.d + 1           # one per round step
     assert {_phase(i[2]) for i in writes} == {"bcast.step"}
+    assert not [i for i in ins if _phase(i[2]) == "bcast.local"]
+    assert fold_schedule(ex.schedule, 4).counters(
+        ex.num_groups)["permutes"] == 104
     pads = [i for i in ins if i[1] == "pad"]
     assert pads and {_phase(i[2]) for i in pads} == {"bcast.place"}
     assert root[1] == "slice" and _phase(root[2]) == "bcast.unstack"
@@ -173,6 +180,50 @@ def test_bbs_broadcast_phases_are_named_for_v5e_2x2(topo):
     rows = ex.num_groups * ex.schedule.K + ex.schedule.num_relay
     assert rows == 102 and "f32[102,5024,128]" in hlo
     assert compiled.memory_analysis().temp_size_in_bytes <= 1.1 * nbytes
+
+
+# each chip's buffer: its nodes' packet rows and a sink row each, end to
+# end (128 x (334 + 1) rows of (96, 128) f32; 32 x (668 + 1) of (192, 128))
+FOLDED = {"one_chip_16m": (1, 16_000_000, "f32[42880,96,128]"),
+          "four_chips_64m": (4, 64_000_000, "f32[21408,192,128]")}
+
+
+@pytest.mark.parametrize("cell", sorted(FOLDED))
+def test_folded_dragonfly_compiles_for_v5e(topo, cell):
+    """dragonfly(128) folded onto one chip at 16e6 B and onto the 2x2 at
+    64e6 B, as the benchmark runs it: each chip holds its nodes' packet
+    buffers end to end, hands rows over inside the chip under
+    ``bcast.local``, runs one collective permute per chip-level
+    permutation of the cycle (none on one chip), and fits a chip with the
+    buffer updated in place (temporaries: the buffer and the unstacking's
+    relayout, each about the output)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from repro import api
+    from repro.core import topology as T
+    from repro.device import fold_schedule
+
+    chips, nbytes, buf = FOLDED[cell]
+    ex = api.compile(T.by_name("dragonfly", 128),
+                     "full_duplex").executable(0, nbytes)
+    mesh = Mesh(np.array(topo.devices[:chips]), ("dev",))
+    x = jax.ShapeDtypeStruct((nbytes // 4,), jnp.float32,
+                             sharding=NamedSharding(mesh, P()))
+    compiled = ex.lower(x, mesh).compile()
+    hlo = compiled.as_text()
+    _, *ins = _instructions(hlo)
+    starts = [i for i in ins if i[1] == "collective-permute-start"]
+    fold = fold_schedule(ex.schedule, chips)
+    assert len(starts) == sum(len(r.permutes) for r in fold.rounds)
+    assert (len(starts) == 0) == (chips == 1)
+    assert any(_phase(i[2]) == "bcast.local" for i in ins)
+    assert buf in hlo
+    mem = compiled.memory_analysis()
+    out = mem.output_size_in_bytes
+    assert out == 128 * nbytes // chips
+    assert mem.temp_size_in_bytes < 3.2 * out
+    assert mem.argument_size_in_bytes + out + mem.temp_size_in_bytes < 16e9
 
 
 def test_kernelsim_core_compiles_for_v5e(one_chip):
