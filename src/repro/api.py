@@ -131,7 +131,7 @@ class CompiledModel:
                    config: Optional[SimConfig] = None):
         """Compile ``(root, nbytes)`` for device execution — an
         ``repro.device.ExecutablePlan`` with static ppermute tables, a
-        donated-buffer jitted runner, and calibration hooks.
+        jitted runner, and calibration hooks.
 
         ``algo="bbs"`` executes the best device-executable candidate of
         ``plan(root)`` (PlanServer-relabeled plans, pinned route overrides

@@ -2,7 +2,7 @@
 
 The sim-to-silicon layer: ``repro.api.compile(topo).executable(root,
 nbytes)`` compiles a plan into an :class:`ExecutablePlan` (static ppermute
-tables + donated-buffer runner + calibration hooks); ``calibrate`` fits
+tables + jitted runner + calibration hooks); ``calibrate`` fits
 per-link-class Hockney constants from measured round times and the
 resulting :class:`CalibratedCost` feeds back into the simulator
 (``apply_calibration``) and ``benchmarks/roofline.py``. See docs/device.md.

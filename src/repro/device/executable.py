@@ -8,7 +8,7 @@ times the broadcast on a jax device mesh:
 
     model = api.compile(T.ring(8, preset="tpu_ici"))
     ex = model.executable(root=0, nbytes=1 << 16)
-    out = ex.run(x, mesh)          # donated-buffer jitted ppermute program
+    out = ex.run(x, mesh)          # jitted ppermute program, x read on the root's chip
     chk = ex.verify(x, mesh)       # bit-exact delivery on every device
     cal = ex.calibrate(mesh)       # fitted Hockney alpha/beta per link class
 
@@ -53,9 +53,13 @@ class DeviceDelivery:
 
 @dataclasses.dataclass
 class ExecutablePlan:
-    """Schedule tables + donated-buffer runner + calibration hooks for one
+    """Schedule tables + jitted runner + calibration hooks for one
     (plan, root, nbytes). Build through ``repro.api`` ``executable()`` or
-    :func:`build_executable`."""
+    :func:`build_executable`.
+
+    ``counters`` counts what ``run`` did: ``runs``, ``payload_moves`` (runs
+    whose payload was not on the root's chip, so it was moved there first)
+    and ``payload_moved_bytes``."""
 
     topo: Topology
     cm: ConflictModel
@@ -69,6 +73,10 @@ class ExecutablePlan:
     info: dict
     device: DeviceConfig
     pipeline: object = None       # the compiled Pipeline (calibration reads it)
+    counters: dict = dataclasses.field(
+        default_factory=lambda: {"runs": 0, "payload_moves": 0,
+                                 "payload_moved_bytes": 0},
+        compare=False, repr=False)
 
     # -- runners -------------------------------------------------------------
 
@@ -98,43 +106,96 @@ class ExecutablePlan:
         return Mesh(np.array(devices), (self.device.axis,))
 
     def _runner(self):
+        """The jitted broadcast program: ``bbs_broadcast_split`` of the
+        payload split over the mesh axis (``_split_input``)."""
         import jax
-        from repro.device.runner import bbs_broadcast
+        from repro.device.runner import bbs_broadcast_split
         fn = self.__dict__.get("_run_fn")
         if fn is None:
-            def run(x, mesh):
-                return bbs_broadcast(
-                    x, mesh, self.device.axis, self.schedule,
+            def run(split, mesh):
+                return bbs_broadcast_split(
+                    split, mesh, self.device.axis, self.schedule,
                     self.num_groups, use_pallas=self.device.use_pallas)
-            # donate the payload buffer: the packet buffer is rewritten in
-            # place across the scan, so the input allocation is reusable
-            fn = self._run_fn = jax.jit(run, static_argnums=1,
-                                        donate_argnums=0)
+            fn = self._run_fn = jax.jit(run, static_argnums=1)
         return fn
+
+    def _split_sharding(self, mesh):
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        return NamedSharding(mesh, P(self.device.axis))
+
+    def _split_input(self, x, mesh):
+        """The program's input for payload ``x`` and the payload bytes moved
+        to build it: ``x`` on the root's chip as that chip's shard, and on
+        every other chip a placeholder of ``x``'s shape, which the program
+        never reads for its values. Assembling the shards moves no data.
+
+        ``x`` is used where it lies if it is on the root's chip alone, or
+        the root chip's own shard is taken if ``x`` is replicated there;
+        otherwise it is moved to the root's chip (the moved bytes)."""
+        import jax
+        import jax.numpy as jnp
+        from repro.device.runner import root_chip
+        if not np.ndim(x):
+            raise ValueError("the payload needs at least one dimension; "
+                             "broadcast x.reshape(1)")
+        devices = list(mesh.devices.flat)
+        root = devices[root_chip(self.schedule, len(devices))]
+        moved = 0
+        if not (isinstance(x, jax.Array) and x.is_fully_replicated
+                and root in x.sharding.device_set):
+            x = jax.device_put(x, root)
+            moved = x.nbytes
+        elif len(x.sharding.device_set) > 1:
+            x = next(s.data for s in x.addressable_shards
+                     if s.device == root)
+        key = (mesh, x.shape, x.dtype)
+        if self.__dict__.get("_placeholder_key") != key:
+            # the last payload's placeholders only: one payload-sized
+            # array on every chip but the root's
+            self._placeholders = {
+                d: jnp.zeros(x.shape, x.dtype, device=d)
+                for d in devices if d != root}
+            self._placeholder_key = key
+        shards = [x if d == root else self._placeholders[d]
+                  for d in devices]
+        split = jax.make_array_from_single_device_arrays(
+            (len(devices) * x.shape[0],) + x.shape[1:],
+            self._split_sharding(mesh), shards)
+        return split, moved
 
     def run(self, x, mesh=None):
         """Execute the broadcast; returns every fabric node's copy stacked
         on a leading axis (shape ``(num_nodes,) + x.shape``), whatever the
-        number of devices the nodes are folded onto."""
+        number of devices the nodes are folded onto. ``x`` is read on the
+        root's chip only (``_split_input``) and is not donated."""
         mesh = mesh or self.mesh()
-        return self._runner()(x, mesh)
+        split, moved = self._split_input(x, mesh)
+        self.counters["runs"] += 1
+        self.counters["payload_moves"] += bool(moved)
+        self.counters["payload_moved_bytes"] += moved
+        return self._runner()(split, mesh)
 
     def lower(self, x, mesh=None):
-        """The program ``run(x, mesh)`` dispatches, lowered for ``x`` (an
-        array or a ``jax.ShapeDtypeStruct`` with its sharding):
-        ``.compile().as_text()`` is the optimized HLO, whose ``op_name``
-        metadata carries the ``bcast.*`` phase scopes."""
-        return self._runner().lower(x, mesh or self.mesh())
+        """The program ``run(x, mesh)`` dispatches, lowered for a payload
+        of ``x``'s shape and dtype (``x`` an array or a
+        ``jax.ShapeDtypeStruct``; where it lies does not change the
+        program): ``.compile().as_text()`` is the optimized HLO, whose
+        ``op_name`` metadata carries the ``bcast.*`` phase scopes. The
+        program's input is the payload split over the mesh axis, one shard
+        of ``x.shape`` a chip."""
+        import jax
+        mesh = mesh or self.mesh()
+        split = jax.ShapeDtypeStruct(
+            (mesh.devices.size * x.shape[0],) + tuple(x.shape[1:]), x.dtype,
+            sharding=self._split_sharding(mesh))
+        return self._runner().lower(split, mesh)
 
     def verify(self, x, mesh=None) -> DeviceDelivery:
         """Run and compare every node's buffer to the payload on raw bytes
         (``verify_delivery`` semantics: no faults => every node of the
         fabric must hold the complete message bit-identically)."""
-        import jax.numpy as jnp
-        # non-destructive: the runner donates its payload, so run a copy
-        # and keep the caller's array (and our reference bytes) intact
-        ref = np.asarray(x).copy()
-        out = np.asarray(self.run(jnp.asarray(ref.copy()), mesh))
+        ref = np.asarray(x)
+        out = np.asarray(self.run(x, mesh))
         required = tuple(range(self.topo.num_nodes))
         missing = tuple(v for v in required
                         if out[v].tobytes() != ref.tobytes())
@@ -144,23 +205,21 @@ class ExecutablePlan:
     def measure(self, x=None, mesh=None, reps: int = 5) -> float:
         """Measured wall-clock seconds per broadcast (min over ``reps``
         timed runs after one warm-up compile), the calibration-side number
-        compared against ``predicted_time``."""
+        compared against ``predicted_time``. The payload is placed on the
+        root's chip before the timed runs."""
         import jax
         import jax.numpy as jnp
         mesh = mesh or self.mesh()
         if x is None:
             n = max(1, int(self.nbytes) // 4)
             x = jnp.arange(n, dtype=jnp.float32)
-        ref = np.asarray(x)
         fn = self._runner()
-        # the runner donates its payload, so every call needs a fresh
-        # buffer; allocate them outside the timed region
-        xs = [jnp.asarray(ref.copy()) for _ in range(reps + 1)]
-        jax.block_until_ready(fn(xs[0], mesh))      # compile + warm up
+        split, _ = self._split_input(x, mesh)
+        jax.block_until_ready(fn(split, mesh))      # compile + warm up
         best = float("inf")
-        for i in range(reps):
+        for _ in range(reps):
             t0 = time.perf_counter()
-            jax.block_until_ready(fn(xs[i + 1], mesh))
+            jax.block_until_ready(fn(split, mesh))
             best = min(best, time.perf_counter() - t0)
         return best
 

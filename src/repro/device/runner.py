@@ -98,11 +98,18 @@ def _pad_packets(x: jax.Array, num_packets: int) -> jax.Array:
     return flat.reshape(num_packets, plen // LANES, LANES)
 
 
-def _unstack(out: jax.Array, x: jax.Array) -> jax.Array:
+def _unstack(out: jax.Array, shape: Tuple[int, ...]) -> jax.Array:
     """The stacked ``(n, rows, *row)`` buffers cut back to ``(n,) +
-    x.shape``: the payload is the leading ``x.size`` words of each."""
+    shape``: the payload is the leading ``prod(shape)`` words of each."""
     n = out.shape[0]
-    return out.reshape(n, -1)[:, :x.size].reshape((n,) + x.shape)
+    size = int(np.prod(shape))
+    return out.reshape(n, -1)[:, :size].reshape((n,) + tuple(shape))
+
+
+def root_chip(sched: DeviceSchedule, chips: int) -> int:
+    """The device of the schedule's root node when its nodes are folded
+    onto ``chips`` devices in contiguous blocks (``fold_schedule``)."""
+    return sched.root // (sched.num_devices // chips)
 
 
 def bbs_broadcast(x: jax.Array, mesh: Mesh, axis: str, sched: DeviceSchedule,
@@ -110,17 +117,38 @@ def bbs_broadcast(x: jax.Array, mesh: Mesh, axis: str, sched: DeviceSchedule,
     """Broadcast `x` from the schedule's root node to every fabric node,
     the nodes folded onto the devices along `axis` in contiguous blocks
     (``fold_schedule``). Returns every node's copy stacked on a leading
-    axis, ``(num_nodes,) + x.shape``.
+    axis, ``(num_nodes,) + x.shape``; `x` has at least one dimension.
 
-    The input is only read on the root's device; other devices' values are
-    ignored (zeroed before the pipeline runs). Relay rows (multi-hop plan
-    edges) live after the ``m*K`` packet rows and are dropped on return.
-    A device holds the packet buffers of all its nodes end to end; with
-    one node per device the program is the single-row cycle of the round
-    step, otherwise the folded cycle (``_folded_cycle``), whose node
-    buffers end in one more row, where writes that hold no row go.
+    The entry for callers that hold `x` inside a jitted program, as every
+    device holds it: each device's own copy becomes its shard of the split
+    input (no data moves) and ``bbs_broadcast_split`` runs on it, so only
+    the root device's copy is read."""
+    split = jax.shard_map(lambda v: v, mesh=mesh, in_specs=P(),
+                          out_specs=P(axis), check_vma=False)(x)
+    return bbs_broadcast_split(split, mesh, axis, sched, num_groups,
+                               use_pallas=use_pallas)
+
+
+def bbs_broadcast_split(split: jax.Array, mesh: Mesh, axis: str,
+                        sched: DeviceSchedule, num_groups: int, *,
+                        use_pallas: bool = False) -> jax.Array:
+    """``bbs_broadcast`` of the payload held by the root's device alone:
+    `split` is split over `axis` into one shard a device, each of the
+    payload's shape; the root device's shard is the payload
+    (``root_chip``), and the other shards are placeholders whose values are
+    never used (zeroed before the pipeline runs). Returns every node's copy
+    stacked on a leading axis, ``(num_nodes,) + payload shape``: no
+    payload byte crosses between devices outside the schedule's permutes.
+
+    Relay rows (multi-hop plan edges) live after the ``m*K`` packet rows
+    and are dropped on return. A device holds the packet buffers of all
+    its nodes end to end; with one node per device the program is the
+    single-row cycle of the round step, otherwise the folded cycle
+    (``_folded_cycle``), whose node buffers end in one more row, where
+    writes that hold no row go.
     """
-    fold = fold_schedule(sched, mesh.shape[axis])
+    chips = mesh.shape[axis]
+    fold = fold_schedule(sched, chips)
     per = fold.nodes_per_chip
     if use_pallas and per > 1:
         raise ValueError(
@@ -131,19 +159,20 @@ def bbs_broadcast(x: jax.Array, mesh: Mesh, axis: str, sched: DeviceSchedule,
     K = sched.K
     total = m * K
     rows = total + sched.num_relay + (per > 1)
-    root_chip, root_slot = divmod(sched.root, per)
-    with jax.named_scope("bcast.place"):
-        packets = _pad_packets(x, total)
-        if sched.num_relay:
-            packets = jnp.concatenate(
-                [packets, jnp.zeros((sched.num_relay,) + packets.shape[1:],
-                                    packets.dtype)])
+    root_slot = sched.root % per
+    shard = (split.shape[0] // chips,) + split.shape[1:]
     num_cycles = sched.num_cycles(m)
 
-    def body(buf_x):
+    def body(x):
         idx = jax.lax.axis_index(axis)
         with jax.named_scope("bcast.place"):
-            buf = jnp.where(idx == root_chip, buf_x, jnp.zeros_like(buf_x))
+            buf = _pad_packets(x, total)
+            if sched.num_relay:
+                buf = jnp.concatenate(
+                    [buf, jnp.zeros((sched.num_relay,) + buf.shape[1:],
+                                    buf.dtype)])
+            buf = jnp.where(idx == root_chip(sched, chips), buf,
+                            jnp.zeros_like(buf))
             if per > 1:
                 buf = jnp.pad(buf, ((root_slot * rows,
                                      (per - root_slot) * rows
@@ -160,10 +189,10 @@ def bbs_broadcast(x: jax.Array, mesh: Mesh, axis: str, sched: DeviceSchedule,
         # the device's nodes on a leading axis
         return buf.reshape((per, rows) + buf.shape[1:])
 
-    out = jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(axis),
-                        check_vma=False)(packets)
+    out = jax.shard_map(body, mesh=mesh, in_specs=P(axis), out_specs=P(axis),
+                        check_vma=False)(split)
     with jax.named_scope("bcast.unstack"):
-        return _unstack(out, x)
+        return _unstack(out, shard)
 
 
 def _rows(rel, ab, c, K: int, total: int):
@@ -374,4 +403,4 @@ def chain_broadcast(x: jax.Array, mesh: Mesh, axis: str, root: int = 0,
     out = jax.shard_map(body, mesh=mesh, in_specs=P(), out_specs=P(axis),
                         check_vma=False)(packets)
     with jax.named_scope("bcast.unstack"):
-        return _unstack(out, x)
+        return _unstack(out, x.shape)

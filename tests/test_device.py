@@ -478,8 +478,9 @@ def test_executable_nonzero_root_and_pallas():
 
 def test_lower_is_the_program_run_dispatches():
     """``ExecutablePlan.lower`` lowers the jitted program that ``run``
-    calls: compiled, it delivers what ``run`` delivers, and its optimized
-    HLO carries the round step's scope."""
+    calls: compiled and called on the input ``run`` builds, it delivers
+    what ``run`` delivers, and its optimized HLO carries the round step's
+    scope."""
     out = run_multidevice("""
         import json
         import jax, jax.numpy as jnp, numpy as np
@@ -489,7 +490,8 @@ def test_lower_is_the_program_run_dispatches():
         mesh = ex.mesh()
         x = np.arange(1024, dtype=np.float32)
         compiled = ex.lower(jnp.asarray(x), mesh).compile()
-        got = np.asarray(compiled(jnp.asarray(x)))
+        split, _ = ex._split_input(jnp.asarray(x), mesh)
+        got = np.asarray(compiled(split))
         ran = np.asarray(ex.run(jnp.asarray(x), mesh))
         print(json.dumps({"same": bool((got == ran).all()),
                           "delivered": bool((got == x[None]).all()),
@@ -497,6 +499,93 @@ def test_lower_is_the_program_run_dispatches():
     """, devices=4)
     got = json.loads(out.strip().splitlines()[-1])
     assert got == {"same": True, "delivered": True, "step": True}
+
+
+# (fabric, mode, root, devices, payload bytes): the payload is made on
+# device 0, as the benchmark makes it on the root's chip
+ROOT_CHIP_RUNS = {
+    "torus_root0": ("torus2d(2, 2)", "full_duplex", 0, 4, 1 << 14),
+    "torus_root3": ("torus2d(2, 2)", "full_duplex", 3, 4, 1 << 14),
+    "dragonfly_fold4": ('by_name("dragonfly", 128)', "full_duplex", 0, 4,
+                        1 << 14),
+    "dragonfly_fold1": ('by_name("dragonfly", 128)', "full_duplex", 0, 1,
+                        1 << 14),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ROOT_CHIP_RUNS))
+def test_run_reads_the_payload_on_the_root_chip_only(case):
+    """``run`` delivers the payload bit for bit to every node from a
+    payload made on device 0. The program it dispatches takes the payload
+    split over the mesh axis, one shard of the payload's shape a device
+    (not replicated), and ``counters`` counts a move only where the root's
+    chip is not device 0: then the payload's bytes, once a run."""
+    fabric, mode, root, devices, nbytes = ROOT_CHIP_RUNS[case]
+    out = run_multidevice(f"""
+        import json
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import PartitionSpec as P
+        from repro import api
+        from repro.core import topology as T
+        ex = api.compile(T.{fabric}, "{mode}").executable({root}, {nbytes})
+        mesh = ex.mesh(jax.devices()[:{devices}])
+        x = jax.device_put(np.random.RandomState(11).rand({nbytes // 4})
+                           .astype(np.float32), jax.devices()[0])
+        outs = [np.asarray(ex.run(x, mesh)) for _ in range(2)]
+        split, _ = ex._split_input(x, mesh)
+        sh = ex.lower(x, mesh).compile().input_shardings[0][0]
+        print(json.dumps({{
+            "exact": [o.shape[0] == ex.topo.num_nodes and all(
+                o[v].tobytes() == np.asarray(x).tobytes()
+                for v in range(o.shape[0])) for o in outs],
+            "spec": sh.spec == P("dev"),
+            "devices": len(sh.device_set),
+            "shard": list(sh.shard_shape(split.shape)),
+            "split": sh == split.sharding,
+            "replicated": sh.is_fully_replicated,
+            "counters": ex.counters}}))
+    """, devices=4)
+    got = json.loads(out.strip().splitlines()[-1])
+    moves = 2 if root else 0
+    assert got == {"exact": [True, True], "spec": True, "devices": devices,
+                   "shard": [nbytes // 4], "split": True,
+                   "replicated": devices == 1,
+                   "counters": {"runs": 2, "payload_moves": moves,
+                                "payload_moved_bytes": moves * nbytes}}
+
+
+def test_run_takes_a_replicated_payload_from_the_root_chips_shard():
+    """A payload replicated over the mesh is not moved: the root chip's
+    own shard, the same buffer, is the program's input there; the other
+    chips' shards are placeholders kept between runs."""
+    out = run_multidevice("""
+        import json
+        import jax, jax.numpy as jnp, numpy as np
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro import api
+        from repro.core import topology as T
+        ex = api.compile(T.torus2d(2, 2)).executable(3, 4096)
+        mesh = ex.mesh()
+        x = jax.device_put(np.arange(1024, dtype=np.float32),
+                           NamedSharding(mesh, P()))
+        ptr = lambda a, d: next(s.data.unsafe_buffer_pointer()
+                                for s in a.addressable_shards
+                                if s.device == d)
+        devs = list(mesh.devices.flat)
+        first, _ = ex._split_input(x, mesh)
+        again, _ = ex._split_input(x, mesh)
+        out = np.asarray(ex.run(x, mesh))
+        print(json.dumps({
+            "exact": bool((out == np.asarray(x)[None]).all()),
+            "root_shard": ptr(first, devs[3]) == ptr(x, devs[3]),
+            "kept": [ptr(first, d) == ptr(again, d) for d in devs[:3]],
+            "counters": ex.counters}))
+    """, devices=4)
+    got = json.loads(out.strip().splitlines()[-1])
+    assert got == {"exact": True, "root_shard": True,
+                   "kept": [True] * 3,
+                   "counters": {"runs": 1, "payload_moves": 0,
+                                "payload_moved_bytes": 0}}
 
 
 # a payload whose rows (12 of 87,357 f32 on torus2d(2, 2)) span many

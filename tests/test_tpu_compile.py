@@ -102,6 +102,8 @@ def test_bbs_broadcast_compiles_for_v5e_2x2(topo):
     txt = compiled.as_text()
     permutes = re.findall(r"collective-permute(?:-start)?\(", txt)
     assert len(permutes) == ex.schedule.d
+    assert _entry_parameter(txt) == f"f32[{nbytes // 4}]"
+    assert not _other_collectives(txt)
     mem = compiled.memory_analysis()
     per_device = (mem.argument_size_in_bytes + mem.output_size_in_bytes
                   + mem.temp_size_in_bytes)
@@ -128,6 +130,35 @@ def _entry(hlo: str) -> str:
     """The text of an HLO module's entry computation."""
     start = hlo.index("\nENTRY ")
     return hlo[start:hlo.index("\n}", start)]
+
+
+def _entry_parameter(hlo: str) -> str:
+    """The shape of the entry computation's one parameter, per chip."""
+    (shape,) = re.findall(r"= (\S+?)(?:\{[^}]*\})? parameter\(0\)",
+                          _entry(hlo))
+    return shape
+
+
+def _other_collectives(hlo: str):
+    """Cross-chip ops of an HLO module other than collective permutes."""
+    return re.findall(r"\b(all-gather|all-reduce|all-to-all|reduce-scatter"
+                      r"|collective-broadcast)", hlo)
+
+
+def _assert_split_input(compiled, mesh, words: int) -> None:
+    """The program takes the payload split over the mesh axis: each chip's
+    parameter is one payload-sized shard (replicated only on one chip),
+    and no cross-chip op but the schedule's permutes moves it."""
+    from jax.sharding import PartitionSpec as P
+    (sharding,), _ = compiled.input_shardings
+    chips = mesh.devices.size
+    assert sharding.spec == P("dev")
+    assert sharding.device_set == set(mesh.devices.flat)
+    assert sharding.shard_shape((chips * words,)) == (words,)
+    assert sharding.is_fully_replicated == (chips == 1)
+    hlo = compiled.as_text()
+    assert _entry_parameter(hlo) == f"f32[{words}]"
+    assert not _other_collectives(hlo)
 
 
 def _phase(op_name: str) -> str:
@@ -180,6 +211,7 @@ def test_bbs_broadcast_phases_are_named_for_v5e_2x2(topo):
     rows = ex.num_groups * ex.schedule.K + ex.schedule.num_relay
     assert rows == 102 and "f32[102,5024,128]" in hlo
     assert compiled.memory_analysis().temp_size_in_bytes <= 1.1 * nbytes
+    _assert_split_input(compiled, mesh, nbytes // 4)
 
 
 # each chip's buffer: its nodes' packet rows and a sink row each, end to
@@ -224,6 +256,7 @@ def test_folded_dragonfly_compiles_for_v5e(topo, cell):
     assert out == 128 * nbytes // chips
     assert mem.temp_size_in_bytes < 3.2 * out
     assert mem.argument_size_in_bytes + out + mem.temp_size_in_bytes < 16e9
+    _assert_split_input(compiled, mesh, nbytes // 4)
 
 
 def test_kernelsim_core_compiles_for_v5e(one_chip):
